@@ -14,6 +14,7 @@ import sys
 import traceback
 
 from benchmarks import common
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     "sketch_error",        # Theorem 1.1
@@ -67,6 +68,7 @@ def main() -> None:
                     help="where to persist serve/prefix-cache cells "
                          "('' disables)")
     args = ap.parse_args()
+    enable_compile_cache()
     filters = [f for f in args.only.split(",") if f]
     print("name,us_per_call,derived")
     failed = []

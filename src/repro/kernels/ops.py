@@ -2,18 +2,17 @@
 
 Every op takes impl in {"pallas", "interpret", "xla"}:
   - "pallas":    compiled Pallas TPU kernel (real hardware target),
-  - "interpret": Pallas interpret mode (CPU-correctness path used in tests),
-  - "xla":       pure-jnp implementation (paper-faithful baseline path; also
-                 the only option under SPMD tracing on the CPU container,
-                 so the dry-run lowers this path).
+  - "interpret": Pallas interpret mode (the CPU-correctness path; runs only
+                 when a caller asks for it, as the kernel tests do),
+  - "xla":       pure-jnp implementation (paper-faithful reference path).
+impl=None picks from the platform JAX compiles for: "pallas" on a TPU and
+"xla" everywhere else.
 
 Batching convention: leading dims (B, H, ...) are flattened to one `bh` axis
 before the kernel and restored after. GQA is handled by repeating kv heads
 to query heads (a deliberate simplicity/VMEM trade-off — keys are small).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,12 +22,21 @@ from repro.core.poly_attention import poly_attention_full
 from repro.kernels import ref as _ref
 from repro.kernels.lt_mult import lt_mult_pallas
 from repro.kernels.poly_flash import poly_flash_pallas
-from repro.kernels.polysketch_causal import (factored_to_z,
+from repro.kernels.polysketch_causal import (join_state,
                                              polysketch_causal_pallas,
-                                             z_to_factored)
+                                             split_state)
 from repro.utils import pad_to_multiple
 
-DEFAULT_IMPL = os.environ.get("REPRO_KERNEL_IMPL", "xla")
+IMPLS = ("pallas", "interpret", "xla")
+
+
+def resolve_impl(impl: str | None) -> str:
+    """The kernel path for `impl`; None means the platform's own."""
+    if impl is None:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl not in IMPLS:
+        raise ValueError(f"impl must be one of {IMPLS} or None, got {impl!r}")
+    return impl
 
 
 def _flatten_bh(*xs):
@@ -39,7 +47,7 @@ def _flatten_bh(*xs):
 
 def lt_mult(a, b, c, *, block_size: int = 256, impl: str | None = None):
     """lt(A B^T) C over the last two axes; leading dims are batch."""
-    impl = impl or DEFAULT_IMPL
+    impl = resolve_impl(impl)
     if impl == "xla":
         return _lt_mult_blocked_xla(a, b, c, block_size=block_size)
     lead, (af, bf, cf) = _flatten_bh(a, b, c)
@@ -84,7 +92,7 @@ def polysketch_attention(qm, km, q, k, v, *, degree: int, scale: float,
     must keep a partial tail un-folded (decode buffers) split the tail off
     first — see core.decode.polysketch_prefill.
     """
-    impl = impl or DEFAULT_IMPL
+    impl = resolve_impl(impl)
     hq, hkv = q.shape[-3], k.shape[-3]
     if hkv != hq:  # GQA: repeat kv to query heads
         g = hq // hkv
@@ -116,17 +124,15 @@ def polysketch_attention(qm, km, q, k, v, *, degree: int, scale: float,
     lead, (qmf, kmf, qf, kf, vf) = _flatten_bh(qm, km, q, k, v)
     zv0 = zd0 = None
     if z0 is not None:
-        zv0, zd0 = z_to_factored(z0.astype(jnp.float32))
-        zv0 = zv0.reshape(-1, *zv0.shape[-2:])
-        zd0 = zd0.reshape(-1, *zd0.shape[-2:])
+        zv0, zd0 = split_state(
+            z0.astype(jnp.float32).reshape(-1, *z0.shape[-2:]))
     out = polysketch_causal_pallas(
         qmf, kmf, qf, kf, vf, zv0, zd0, degree=degree, scale=scale,
         local_exact=local_exact, block_size=blk,
         interpret=(impl == "interpret"), return_state=return_state)
     if return_state:
         out, zv, zd = out
-        z = factored_to_z(zv.reshape(*lead, *zv.shape[-2:]),
-                          zd.reshape(*lead, *zd.shape[-2:]))
+        z = join_state(zv, zd).reshape(*lead, *zv.shape[-2:-1], -1)
         return out.reshape(*lead, *out.shape[-2:])[..., :n, :], z
     out = out.reshape(*lead, *out.shape[-2:])
     return out[..., :n, :]
@@ -136,7 +142,7 @@ def poly_attention(q, k, v, *, degree: int, scale: float | None = None,
                    causal: bool = True, block_q: int = 256,
                    block_kv: int = 256, impl: str | None = None):
     """Exact (quadratic) polynomial attention. q,k,v: (B, H, S, h)."""
-    impl = impl or DEFAULT_IMPL
+    impl = resolve_impl(impl)
     if scale is None:
         scale = 1.0 / q.shape[-1]
     hq, hkv = q.shape[-3], k.shape[-3]
@@ -147,12 +153,18 @@ def poly_attention(q, k, v, *, degree: int, scale: float | None = None,
     if impl == "xla":
         return poly_attention_full(q, k, v, degree=degree, scale=scale,
                                    causal=causal)
+    # zero-pad to whole blocks: a zero key gets weight <q, 0>^p = 0, and
+    # padded query rows are sliced off
+    n, t = q.shape[-2], k.shape[-2]
+    q, _ = pad_to_multiple(q, min(block_q, n), axis=-2)
+    k, _ = pad_to_multiple(k, min(block_kv, t), axis=-2)
+    v, _ = pad_to_multiple(v, min(block_kv, t), axis=-2)
     lead, (qf, kf, vf) = _flatten_bh(q, k, v)
     out = poly_flash_pallas(qf, kf, vf, degree=degree, scale=scale,
                             causal=causal, block_q=block_q,
                             block_kv=block_kv,
                             interpret=(impl == "interpret"))
-    return out.reshape(*lead, *out.shape[-2:])
+    return out.reshape(*lead, *out.shape[-2:])[..., :n, :]
 
 
 REFS = {

@@ -9,23 +9,15 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    # and older versions only support auto meshes anyway.
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_serving_mesh(n_devices: int, model_parallel: int = 1):

@@ -64,6 +64,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.distributed.fault import PreemptionGuard
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.models import build_model
 from repro.serve import (ChaosInjector, Overloaded, PrefixCache,
@@ -238,6 +239,7 @@ def main(argv=None):
                          "the CI mesh bit-parity gate diffs these files")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.expect_no_retraces and not args.warm:
         raise SystemExit("--expect-no-retraces requires --warm (without a "
                          "warm-up pass every compile is expected, so the "
@@ -356,29 +358,35 @@ def main(argv=None):
         # retrace. Warm prompts come from an independent stream: the
         # workload's prompt sequence is identical with and without --warm.
         wrng = np.random.default_rng(args.seed + 104729)
-        warm_lens = ([args.prompt_len] if args.shared_prefix
-                     else sorted({max(1, args.prompt_len // 2),
-                                  max(1, 3 * args.prompt_len // 4),
-                                  args.prompt_len}))
-        if replica_mode:
-            # every replica compiles its own traces (engines do not share
-            # jit caches), so each one warms directly — the coordinator's
-            # chaos tick counter never advances during warm-up
-            for eng in engine.engines:
-                for plen in warm_lens:
-                    eng.submit(wrng.integers(0, cfg.vocab_size,
-                                             size=plen).astype(np.int32),
-                               min(4, args.gen), None)
-                eng.run()
-        else:
-            for plen in warm_lens:
-                engine.submit(wrng.integers(0, cfg.vocab_size,
-                                            size=plen).astype(np.int32),
-                              min(4, args.gen), None)
-            engine.run()
+
+        def warm_prompts():
+            if args.shared_prefix:
+                # three prompts sharing a warm-up prefix of their own: the
+                # third hits the prefix cache, so the restore path compiles
+                # here and not on the workload's first hit
+                wshared = wrng.integers(0, cfg.vocab_size,
+                                        size=args.shared_prefix)
+                return [np.concatenate([wshared, wrng.integers(
+                    0, cfg.vocab_size,
+                    size=args.prompt_len - args.shared_prefix)]
+                ).astype(np.int32) for _ in range(3)]
+            return [wrng.integers(0, cfg.vocab_size,
+                                  size=plen).astype(np.int32)
+                    for plen in sorted({max(1, args.prompt_len // 2),
+                                        max(1, 3 * args.prompt_len // 4),
+                                        args.prompt_len})]
+
+        # every replica compiles its own traces (engines do not share jit
+        # caches), so each one warms directly — the coordinator's chaos
+        # tick counter never advances during warm-up
+        for eng in (engine.engines if replica_mode else [engine]):
+            prompts = warm_prompts()
+            for prompt in prompts:
+                eng.submit(prompt, min(4, args.gen), None)
+            eng.run()
         engine.reset_stats()
-        print(f"warm-up: {len(warm_lens)} requests "
-              f"(lengths {warm_lens}), watchdog armed")
+        print(f"warm-up: {len(prompts)} requests (lengths "
+              f"{sorted({len(p) for p in prompts})}), watchdog armed")
 
     t = 0.0
     arrivals = []
@@ -441,7 +449,8 @@ def main(argv=None):
               f"(+{stats['checkpoints_dropped']} chaos-dropped) "
               f"shed={stats['shed']} "
               f"recovered={stats['recovered_installs']} "
-              f"straggler_flags={stats['straggler_flags']}")
+              f"straggler_flags={stats['straggler_flags']}"
+              + "".join(f"\n  death: {d}" for d in stats["death_log"]))
         # the no-lost-requests gate: across deaths, failovers and
         # shedding, every arrival is served exactly once
         rids = [o.rid for o in outs]

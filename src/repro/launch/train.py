@@ -23,6 +23,7 @@ from repro.configs import TrainConfig, get_config
 from repro.data import DataIterator, make_markov_lm
 from repro.distributed.fault import PreemptionGuard, StragglerDetector
 from repro.distributed.sharding import batch_shardings, shardings_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train import init_train_state, make_train_step
@@ -47,6 +48,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--set", action="append", default=[])
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
@@ -96,11 +98,13 @@ def main(argv=None):
         batch = next(it)
         straggler.start()
         state, metrics = step_fn(state, batch)
-        jax.block_until_ready(metrics["loss"])
+        loss = float(metrics["loss"])  # waits for the step
         straggler.stop()
+        if not np.isfinite(loss):
+            raise SystemExit(f"step {i + 1}: loss is {loss}")
         if (i + 1) % args.log_every == 0 or i == start_step:
             log.info("step %d loss %.4f lr %.2e grad_norm %.3f",
-                     i + 1, float(metrics["loss"]), float(metrics["lr"]),
+                     i + 1, loss, float(metrics["lr"]),
                      float(metrics["grad_norm"]))
         save_now = ckpt and args.ckpt_every and (i + 1) % args.ckpt_every == 0
         if save_now or (ckpt and guard.preempted):

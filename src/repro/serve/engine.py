@@ -154,6 +154,66 @@ def generate(model, cfg, params, prompt: jax.Array, steps: int, *,
     return GenerationResult(tokens=toks.T, logits_last=last)
 
 
+def make_decode_tick(state, plan: ServePlan, logprobs: bool):
+    """The body of the engine's jitted decode tick over every slot:
+    decode_all(params, toks, pos, keys, samp, caches, active) ->
+    (tokens, logprobs, toks, pos, keys, caches). Trace it inside
+    `plan.activation_context()`."""
+
+    def decode_one(params, tok, pos, cache):
+        logits, cache = state.decode_step(params, tok, pos, cache)
+        return logits[0], cache
+
+    def decode_all(params, toks, pos, keys, samp, caches, active):
+        # model tick for all slots, then sampling OUTSIDE the vmap so
+        # a scalar lax.cond can skip the sampler ops entirely for
+        # all-greedy batches (a vmapped cond would lower to select and
+        # run both branches) — greedy-only serving keeps the pre-
+        # sampling argmax-tick cost. Free slots' stale params are
+        # ignored by the predicate (`| ~active`): a retired sampled
+        # request must not force the sampler on a greedy drain. Greedy
+        # slots never consume their PRNG stream, so the fast path
+        # leaving keys un-split is not observable in any request's
+        # tokens.
+        logits, caches = jax.vmap(decode_one, in_axes=(None, 0, 0, 0))(
+            params, toks, pos, caches)
+        # gather the vocab dim before softmax/argmax: the reductions
+        # below must see identically-ordered operands on every mesh
+        logits = plan.constrain_logits(logits)
+
+        def all_greedy(_):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), keys
+
+        def mixed(_):
+            return jax.vmap(sample_step)(keys, logits, samp.temperature,
+                                         samp.top_k, samp.top_p,
+                                         samp.greedy)
+
+        out, new_keys = jax.lax.cond(jnp.all(samp.greedy | ~active),
+                                     all_greedy, mixed, None)
+        if logprobs:
+            # raw-distribution logprob of the emitted token, fused into
+            # the tick (logprobs is trace-static)
+            lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            lps = jnp.take_along_axis(lsm, out[:, None], axis=-1)[:, 0]
+        else:
+            lps = jnp.zeros((out.shape[0],), jnp.float32)
+        # free slots decode along on stale state but their feed token,
+        # PRNG key, and position are all FROZEN here (one fused tick,
+        # no per-field host dispatch): admission rewrites the whole
+        # slot, yet a retire -> step -> admit interleaving must never
+        # observe stale-decode garbage in a free slot's state, and a
+        # long drain must never push pos past max_len (KV-cache state
+        # kinds index their cache at pos; RoPE stays bounded)
+        new_toks = jnp.where(active[:, None, None], out[:, None, None],
+                             toks)
+        new_keys = jnp.where(active[:, None], new_keys, keys)
+        new_pos = jnp.where(active, pos + 1, pos)
+        return out, lps, new_toks, new_pos, new_keys, caches
+
+    return decode_all
+
+
 @dataclass
 class Request:
     rid: int
@@ -421,56 +481,7 @@ class ServeEngine:
                 samp, type(samp)(t, k, p, g))
             return caches, toks, pos, keys, samp, tok, lp
 
-        def decode_one(params, tok, pos, cache):
-            logits, cache = state.decode_step(params, tok, pos, cache)
-            return logits[0], cache
-
-        def decode_all(params, toks, pos, keys, samp, caches, active):
-            # model tick for all slots, then sampling OUTSIDE the vmap so
-            # a scalar lax.cond can skip the sampler ops entirely for
-            # all-greedy batches (a vmapped cond would lower to select and
-            # run both branches) — greedy-only serving keeps the pre-
-            # sampling argmax-tick cost. Free slots' stale params are
-            # ignored by the predicate (`| ~active`): a retired sampled
-            # request must not force the sampler on a greedy drain. Greedy
-            # slots never consume their PRNG stream, so the fast path
-            # leaving keys un-split is not observable in any request's
-            # tokens.
-            logits, caches = jax.vmap(decode_one, in_axes=(None, 0, 0, 0))(
-                params, toks, pos, caches)
-            # gather the vocab dim before softmax/argmax: the reductions
-            # below must see identically-ordered operands on every mesh
-            logits = self.plan.constrain_logits(logits)
-
-            def all_greedy(_):
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), keys
-
-            def mixed(_):
-                return jax.vmap(sample_step)(keys, logits, samp.temperature,
-                                             samp.top_k, samp.top_p,
-                                             samp.greedy)
-
-            out, new_keys = jax.lax.cond(jnp.all(samp.greedy | ~active),
-                                         all_greedy, mixed, None)
-            if self.logprobs:
-                # raw-distribution logprob of the emitted token, fused into
-                # the tick (self.logprobs is trace-static)
-                lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-                lps = jnp.take_along_axis(lsm, out[:, None], axis=-1)[:, 0]
-            else:
-                lps = jnp.zeros((out.shape[0],), jnp.float32)
-            # free slots decode along on stale state but their feed token,
-            # PRNG key, and position are all FROZEN here (one fused tick,
-            # no per-field host dispatch): admission rewrites the whole
-            # slot, yet a retire -> step -> admit interleaving must never
-            # observe stale-decode garbage in a free slot's state, and a
-            # long drain must never push pos past max_len (KV-cache state
-            # kinds index their cache at pos; RoPE stays bounded)
-            new_toks = jnp.where(active[:, None, None], out[:, None, None],
-                                 toks)
-            new_keys = jnp.where(active[:, None], new_keys, keys)
-            new_pos = jnp.where(active, pos + 1, pos)
-            return out, lps, new_toks, new_pos, new_keys, caches
+        decode_all = make_decode_tick(state, self.plan, self.logprobs)
 
         def replay_tokens(params, tokens, pos0, cache):
             # decode-path REPLAY of already-emitted tokens (failover

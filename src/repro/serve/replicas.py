@@ -157,6 +157,9 @@ class ReplicaSet:
         self._next_grid = 0
         self.finished: list[RequestOutput] = []
         self._deaths: dict[str, int] = {}
+        # one "replicaI cause: Type: message" line per death — a crash
+        # nobody injected must be readable, not just counted
+        self._death_log: list[str] = []
         self._n_failovers = 0
         self._n_ckpts = 0
         self._n_ckpt_dropped = 0
@@ -276,13 +279,17 @@ class ReplicaSet:
 
     # -- failure handling --------------------------------------------------
 
-    def _fail(self, i: int, cause: str):
+    def _fail(self, i: int, cause: str, error: BaseException | None = None):
         """Replica i is dead: discard it atomically (its un-mirrored tick
         never happened) and re-home every request it owned onto the
-        survivors, deepest-checkpoint first."""
+        survivors, deepest-checkpoint first. `error` is the exception the
+        replica's tick raised, kept in the death log."""
         self._alive[i] = False
         self._beats[i] = time.monotonic()
         self._deaths[cause] = self._deaths.get(cause, 0) + 1
+        self._death_log.append(
+            f"replica{i} {cause}"
+            + (f": {type(error).__name__}: {error}" if error else ""))
         tr = self.telemetry.tracer
         if tr:
             tr.instant(f"replica{i}", "replica_dead", cause=cause)
@@ -347,7 +354,7 @@ class ReplicaSet:
                 outs = eng.step()
             except Exception as e:  # noqa: BLE001 — any tick failure is a death
                 self._fail(i, "kill" if isinstance(e, ReplicaKilled)
-                           else "crash")
+                           else "crash", e)
                 continue
             dt = time.perf_counter() - t0
             self._ticks[i] += 1
@@ -433,6 +440,7 @@ class ReplicaSet:
         self.finished = []
         self._done = set()
         self._deaths = {}
+        self._death_log = []
         self._n_failovers = self._n_ckpts = self._n_ckpt_dropped = 0
         self._n_shed = self._n_dups = 0
         self.telemetry.reset()
@@ -446,6 +454,7 @@ class ReplicaSet:
             "replicas": self.n,
             "alive": sum(self._alive),
             "deaths": dict(self._deaths),
+            "death_log": list(self._death_log),
             "failovers": self._n_failovers,
             "checkpoints": self._n_ckpts,
             "checkpoints_dropped": self._n_ckpt_dropped,

@@ -35,7 +35,8 @@ def make_loss_fn(model, cfg):
             if k in batch:
                 inp[k] = batch[k]
         targets = tokens[:, 1:]
-        logits, _, aux = model.apply(params, inp, mode="train")
+        # the Pallas kernels have no VJP yet, so gradients take the XLA path
+        logits, _, aux = model.apply(params, inp, mode="train", impl="xla")
         logits = logits.astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
@@ -112,8 +113,6 @@ def make_manual_dp_train_step(model, cfg, tcfg, mesh, dp_axis: str = "data"):
 
     Params are replicated over dp_axis here (pure-DP demonstration path;
     production pjit path uses FSDP sharding instead)."""
-    from jax.experimental.shard_map import shard_map
-
     loss_fn = make_loss_fn(model, cfg)
     schedule = linear_warmup_linear_decay(tcfg.peak_lr, tcfg.steps,
                                           tcfg.warmup_frac)
@@ -137,9 +136,9 @@ def make_manual_dp_train_step(model, cfg, tcfg, mesh, dp_axis: str = "data"):
                     jax.tree_util.tree_map(lambda _: P(dp_axis), batch))
         out_specs = (jax.tree_util.tree_map(lambda _: P(), state.params),
                      {k: P() for k in ["loss", "ce_loss"]})
-        grads, metrics = shard_map(
+        grads, metrics = jax.shard_map(
             sharded_grads, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs, check_rep=False)(state.params, batch)
+            out_specs=out_specs, check_vma=False)(state.params, batch)
         grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
         lr = schedule(state.step)
         new_params, new_opt = adamw_update(

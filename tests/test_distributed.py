@@ -11,7 +11,6 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.distributed.compression import int8_allreduce_mean, tree_psum_mean
@@ -25,8 +24,8 @@ def f_exact(xs):
 def f_int8(xs):
     return int8_allreduce_mean(xs, "data", 8)
 
-exact = shard_map(f_exact, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(x)
-comp = shard_map(f_int8, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(x)
+exact = jax.shard_map(f_exact, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(x)
+comp = jax.shard_map(f_int8, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(x)
 err = float(jnp.abs(exact - comp).max() / jnp.abs(exact).max())
 assert err < 0.05, f"int8 allreduce rel err {err}"
 print("INT8_OK", err)
@@ -52,7 +51,7 @@ for compression in ("none", "int8"):
 # the int8-compressed collective moves ~4x fewer bytes (HLO inspection)
 import re
 def coll_bytes(fn):
-    lowered = jax.jit(shard_map(fn, mesh=mesh, in_specs=P("data"),
+    lowered = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
                                 out_specs=P("data"))).lower(x)
     from repro.launch.hlo import parse_collectives
     return parse_collectives(lowered.compile().as_text(), 8)["total_bytes"]

@@ -76,6 +76,21 @@ def test_poly_flash_sweep(degree, causal, dtype, impl):
                                np.array(want, np.float32), atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("causal", [True, False])
+def test_poly_flash_unaligned_seq_padding(causal):
+    """The Pallas path pads q/k/v to whole blocks with zero keys."""
+    B, H, S, hd = 1, 2, 77, 16
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (_rand(kk, (B, H, S, hd), jnp.float32) for kk in ks)
+    out = ops.poly_attention(q, k, v, degree=4, scale=1.0 / hd,
+                             causal=causal, block_q=32, block_kv=32,
+                             impl="interpret")
+    want = ref.poly_flash_ref(q, k, v, degree=4, scale=1.0 / hd,
+                              causal=causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+
+
 # Seeded stand-in for the former hypothesis property test: a fixed sweep
 # over (n, blk, seed) drawn from the same strategy space.
 @pytest.mark.parametrize("n,blk", [(32, 16), (32, 32), (64, 16), (64, 32),
@@ -148,3 +163,36 @@ def test_kernel_grid_state_reset_between_heads():
         scale=1.0 / hd, block_size=16, impl="interpret")
     np.testing.assert_allclose(np.array(out[:, 2:]), np.array(out_solo),
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("local_exact", [True, False])
+def test_polysketch_row_tiles_match_reference(local_exact):
+    """Blocks wider than the kernel's 256-row tile are walked tile by tile
+    (the published block is 1024); outputs and the returned state must
+    match the oracle and the XLA block path."""
+    B, H, S, hd, r, blk = 1, 1, 1024, 8, 4, 512
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    qm, km = (_rand(k, (B, H, S, r), jnp.float32) * 0.5 for k in ks[:2])
+    q, k, v = (_rand(kk, (B, H, S, hd), jnp.float32) for kk in ks[2:])
+    kw = dict(degree=4, scale=1.0 / hd, block_size=blk,
+              local_exact=local_exact, return_state=True)
+    out, z = ops.polysketch_attention(qm, km, q, k, v, impl="interpret",
+                                      **kw)
+    _, z_xla = ops.polysketch_attention(qm, km, q, k, v, impl="xla", **kw)
+    want = ref.polysketch_causal_ref(qm, km, q, k, v, degree=4,
+                                     scale=1.0 / hd, block_size=blk,
+                                     local_exact=local_exact)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(z), np.asarray(z_xla),
+                               atol=1e-4, rtol=1e-5)
+
+
+def test_kernel_path_follows_the_platform(monkeypatch):
+    """impl=None is the platform's own path; interpret only on request."""
+    assert ops.resolve_impl(None) == "xla"  # the CPU test backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_impl(None) == "pallas"
+    assert ops.resolve_impl("interpret") == "interpret"
+    with pytest.raises(ValueError):
+        ops.resolve_impl("mosaic")

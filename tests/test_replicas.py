@@ -89,6 +89,46 @@ def test_replica_plans_single_device_fallback():
     assert len(plans) == 3
 
 
+_PLACEMENT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.configs import get_config
+from repro.models import build_model
+from repro.serve import ReplicaSet
+
+cfg = get_config("gpt2s-polysketch", smoke=True)
+model = build_model(cfg)
+params, _ = model.init(jax.random.PRNGKey(0))
+rs = ReplicaSet(model, cfg, params, n_replicas=4, slots=2, max_len=64)
+rng = np.random.default_rng(0)
+for _ in range(4):
+    rs.submit(rng.integers(0, cfg.vocab_size, 20).astype(np.int32), 4)
+outs = rs.run()
+devs = jax.devices()
+for i, eng in enumerate(rs.engines):
+    for tree in (eng.params, eng._slot_caches):
+        for leaf in jax.tree_util.tree_leaves(tree):
+            assert leaf.sharding.device_set == {devs[i]}, (i, leaf.sharding)
+st = rs.stats()
+assert st["deaths"] == {} and len(outs) == 4, st
+assert all(e["requests"] >= 1 for e in st["engines"].values()), st
+print("PLACEMENT_OK")
+"""
+
+
+def test_replica_plans_one_device_per_replica():
+    """With one device per replica, each engine's parameters and caches
+    live on its own device (read from the arrays, not from the plan)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "PLACEMENT_OK" in out.stdout
+
+
 # -- failover bit-parity across kill ticks and state families ------------
 #
 # The acceptance gate: kill a replica mid-decode and the recovered
@@ -242,6 +282,26 @@ def test_slow_tick_is_straggler_not_death(psk, psk_baseline):
     assert st["deaths"] == {} and st["failovers"] == 0
     assert any(f.startswith("slow-tick") for f in chaos.fired)
     _assert_bit_identical(gids, outs, gids0, outs0, "slow-tick")
+
+
+def test_crash_keeps_exception_in_death_log(psk):
+    """A tick that raises something nobody injected is a death whose
+    exception type and message stay readable in stats()."""
+    _, model, cfg, params, prompts, _ = psk
+    rs = ReplicaSet(model, cfg, params, n_replicas=2, slots=2, max_len=512)
+    eng0 = rs.engines[0]
+
+    def broken_step():
+        raise RuntimeError("device 0 fell over")
+
+    eng0.step = broken_step
+    gids = [rs.submit(p, 4) for p in prompts]
+    outs = rs.run()
+    st = rs.stats()
+    assert st["deaths"] == {"crash": 1}
+    assert st["death_log"] == ["replica0 crash: RuntimeError: device 0 "
+                               "fell over"]
+    assert sorted(o.rid for o in outs) == sorted(gids)
 
 
 def test_shed_above_raises_overloaded(psk):

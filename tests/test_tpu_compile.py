@@ -1,0 +1,147 @@
+"""Compile the main path's kernels and serving programs at the published
+widths of gpt2s-polysketch for a described (not attached) TPU v5e chip.
+
+Nothing runs: the chip's compiler refuses here what it would refuse on the
+chip — a vector layout Mosaic cannot lower, more VMEM than a kernel may
+use, or a program larger than the chip's memory. The topology is described
+inside a fixture, so importing this file loads no TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.lt_mult import lt_mult_pallas
+from repro.kernels.poly_flash import poly_flash_pallas
+from repro.kernels.polysketch_causal import polysketch_causal_pallas
+from repro.models import build_model
+from repro.serve.engine import make_decode_tick
+from repro.serve.plan import ServePlan
+from repro.serve.sampling import init_slot_keys, init_slot_sampling
+
+HBM_BYTES = 16 * 10**9          # one v5e chip
+SLOTS, MAX_LEN, CHUNK = 8, 3072 + 32, 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e:2x2 topology, with the persistent compilation cache
+    off: a compile for a described chip is written to the cache but cannot
+    be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        if log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def model_shapes(one_chip):
+    """gpt2s-polysketch at published widths: model, state protocol,
+    abstract parameters placed on the described chip, and a placement."""
+    cfg = get_config("gpt2s-polysketch")
+    model = build_model(cfg)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0])
+    mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    return cfg, model, _on(params, one_chip), ServePlan.from_mesh(mesh)
+
+
+def _on(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _fits_one_chip(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used < HBM_BYTES, used
+
+
+@pytest.mark.parametrize("return_state", [False, True])
+def test_polysketch_causal_compiles(one_chip, return_state):
+    bh, n, r, h = 12, 2048, 32, 64
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    seq = (f32(bh, n, r), f32(bh, n, r), f32(bh, n, h), f32(bh, n, h),
+           f32(bh, n, h))
+    state = (f32(bh, r * r, h), f32(bh, 1, r * r)) if return_state else ()
+
+    def fn(*a):
+        return polysketch_causal_pallas(*a, degree=4, scale=1.0 / h,
+                                        block_size=1024,
+                                        return_state=return_state)
+
+    compiled = jax.jit(fn).lower(*seq, *state).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_poly_flash_compiles(one_chip):
+    x = jax.ShapeDtypeStruct((12, 2048, 64), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: poly_flash_pallas(
+        q, k, v, degree=4, scale=1.0 / 64)).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_lt_mult_compiles(one_chip):
+    ab = jax.ShapeDtypeStruct((12, 2048, 32), jnp.float32, sharding=one_chip)
+    c = jax.ShapeDtypeStruct((12, 2048, 64), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda a, b, c: lt_mult_pallas(a, b, c)).lower(
+        ab, ab, c).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_decode_tick_compiles(one_chip, model_shapes):
+    cfg, model, params, plan = model_shapes
+    st = model.state
+    caches = _on(jax.eval_shape(
+        lambda p: st.broadcast_slots(st.init_slot(p, MAX_LEN), SLOTS),
+        params), one_chip)
+    s = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    keys = _on(jax.eval_shape(lambda: init_slot_keys(SLOTS)), one_chip)
+    samp = _on(jax.eval_shape(lambda: init_slot_sampling(SLOTS)), one_chip)
+    tick = make_decode_tick(st, plan, logprobs=True)
+    with plan.activation_context():
+        compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+            params, s((SLOTS, 1, 1), jnp.int32), s((SLOTS,), jnp.int32),
+            keys, samp, caches, s((SLOTS,), jnp.bool_)).compile()
+    _fits_one_chip(compiled)
+
+
+def test_engine_prefill_chunk_compiles(one_chip, model_shapes):
+    cfg, model, params, plan = model_shapes
+    st = model.state
+    cache = _on(jax.eval_shape(lambda p: st.init_slot(p, MAX_LEN), params),
+                one_chip)
+    with plan.activation_context():
+        compiled = jax.jit(st.resume).lower(
+            params,
+            jax.ShapeDtypeStruct((1, CHUNK), jnp.int32, sharding=one_chip),
+            cache, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        ).compile()
+    _fits_one_chip(compiled)
