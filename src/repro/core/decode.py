@@ -88,7 +88,11 @@ def polysketch_decode_step(cache: PolysketchCache, qm, km, q, k, v, *,
         kf = self_kron(mbuf)                                   # (B,Hkv,blk,r^2)
         return z + jnp.einsum("bnsf,bnsd->bnfd", kf, vv)
 
-    z = jax.lax.cond(fill == blk - 1, fold, lambda z: z, cache.z)
+    # the scope holds the cond itself: under the serve engine's vmap the
+    # cond lowers to a select over both branches, and that select is fold
+    # work too
+    with jax.named_scope("fold"):
+        z = jax.lax.cond(fill == blk - 1, fold, lambda z: z, cache.z)
     new_cache = PolysketchCache(z=z, kbuf=kbuf, vbuf=vbuf, mbuf=mbuf,
                                 pos=cache.pos + 1)
     return out.astype(v.dtype), new_cache

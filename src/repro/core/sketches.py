@@ -157,7 +157,8 @@ def _apply_withneg(params, x, q: int, learned: bool):
 
 def sketch_half(params, x, degree: int, learned: bool):
     """Degree-p/2 sketch m(x) in R^r with <m(q),m(k)>^2 ~= <q,k>^p."""
-    return _apply_withneg(params, x, degree // 2, learned)
+    with jax.named_scope("sketch"):
+        return _apply_withneg(params, x, degree // 2, learned)
 
 
 def nonneg_features(params, x, degree: int, learned: bool):

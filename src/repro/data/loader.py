@@ -29,15 +29,18 @@ class DataIterator:
         return self
 
     def __next__(self):
-        out = self._fn(self.batch, self.seq, self.step)
-        self.step += 1
-        if isinstance(out, tuple):
-            batch = {"tokens": out[0], "loss_mask": out[1]}
-        else:
-            batch = {"tokens": out}
-        if self.sharding is not None:
-            batch = {k: jax.device_put(v, self.sharding[k] if isinstance(
-                self.sharding, dict) else self.sharding) for k, v in batch.items()}
+        # a host span in a profiler trace, beside the device ops it feeds
+        with jax.profiler.TraceAnnotation("train.feed"):
+            out = self._fn(self.batch, self.seq, self.step)
+            self.step += 1
+            if isinstance(out, tuple):
+                batch = {"tokens": out[0], "loss_mask": out[1]}
+            else:
+                batch = {"tokens": out}
+            if self.sharding is not None:
+                batch = {k: jax.device_put(v, self.sharding[k] if isinstance(
+                    self.sharding, dict) else self.sharding)
+                    for k, v in batch.items()}
         return batch
 
     # --- checkpointable state ---

@@ -111,26 +111,31 @@ def lm_init(key, cfg):
 def _apply_block(bp, cfg, h, mixer_kind, ffn_kind, *, positions, mode, cache,
                  impl):
     h = shard_act(h, "batch", "seq", "embed")
-    hn = norm_apply(bp["norm1"], h)
-    if mixer_kind in ("attn", "local_attn"):
-        y, new_cache = attn.attention_apply(
-            bp["mixer"], cfg, hn, kind=mixer_kind, positions=positions,
-            mode=mode, cache=cache, impl=impl)
-    elif mixer_kind == "rglru":
-        y, new_cache = rglru_mod.rglru_apply(bp["mixer"], cfg, hn, mode=mode,
+    # the scope names ("mixer", "ffn", and "embed", "logits" in lm_apply)
+    # label the compiled ops in a device trace; tests pin the vocabulary
+    with jax.named_scope("mixer"):
+        hn = norm_apply(bp["norm1"], h)
+        if mixer_kind in ("attn", "local_attn"):
+            y, new_cache = attn.attention_apply(
+                bp["mixer"], cfg, hn, kind=mixer_kind, positions=positions,
+                mode=mode, cache=cache, impl=impl)
+        elif mixer_kind == "rglru":
+            y, new_cache = rglru_mod.rglru_apply(bp["mixer"], cfg, hn,
+                                                 mode=mode, cache=cache)
+        else:
+            y, new_cache = ssm_mod.ssm_apply(bp["mixer"], cfg, hn, mode=mode,
                                              cache=cache)
-    else:
-        y, new_cache = ssm_mod.ssm_apply(bp["mixer"], cfg, hn, mode=mode,
-                                         cache=cache)
-    h = h + y
+        h = h + y
     if "ffn" not in bp:  # mixer-only block (mamba2)
         return h, new_cache, {}
-    hn = norm_apply(bp["norm2"], h)
-    if ffn_kind == "moe":
-        y, aux = moe_mod.moe_apply(bp["ffn"], cfg, hn)
-    else:
-        y, aux = glu_ffn_apply(bp["ffn"], hn), {}
-    return h + y, new_cache, aux
+    with jax.named_scope("ffn"):
+        hn = norm_apply(bp["norm2"], h)
+        if ffn_kind == "moe":
+            y, aux = moe_mod.moe_apply(bp["ffn"], cfg, hn)
+        else:
+            y, aux = glu_ffn_apply(bp["ffn"], hn), {}
+        h = h + y
+    return h, new_cache, aux
 
 
 def _zero_aux(cfg):
@@ -157,7 +162,9 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
     n_groups, rem = divmod(cfg.n_layers, g)
     dt = jnp.dtype(cfg.compute_dtype)
 
-    h = params["embed"]["table"].astype(dt)[tokens] * math.sqrt(cfg.d_model)
+    with jax.named_scope("embed"):
+        h = (params["embed"]["table"].astype(dt)[tokens]
+             * math.sqrt(cfg.d_model))
     if image_embeds is not None and cfg.n_image_tokens:
         n_img = image_embeds.shape[1]
         h = jnp.concatenate([image_embeds.astype(dt), h[:, n_img:]], axis=1)
@@ -223,11 +230,13 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
             new_tail[f"block{bi}"] = nc
             aux_total = _add_aux(aux_total, aux)
 
-    h = norm_apply(params["final_norm"], h)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"]["table"].astype(dt))
-    else:
-        logits = h @ params["lm_head"].astype(dt)
+    with jax.named_scope("logits"):
+        h = norm_apply(params["final_norm"], h)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", h,
+                                params["embed"]["table"].astype(dt))
+        else:
+            logits = h @ params["lm_head"].astype(dt)
 
     new_cache = None
     if mode in ("decode", "prefill"):

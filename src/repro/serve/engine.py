@@ -189,15 +189,17 @@ def make_decode_tick(state, plan: ServePlan, logprobs: bool):
                                          samp.top_k, samp.top_p,
                                          samp.greedy)
 
-        out, new_keys = jax.lax.cond(jnp.all(samp.greedy | ~active),
-                                     all_greedy, mixed, None)
-        if logprobs:
-            # raw-distribution logprob of the emitted token, fused into
-            # the tick (logprobs is trace-static)
-            lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            lps = jnp.take_along_axis(lsm, out[:, None], axis=-1)[:, 0]
-        else:
-            lps = jnp.zeros((out.shape[0],), jnp.float32)
+        with jax.named_scope("sampler"):
+            out, new_keys = jax.lax.cond(jnp.all(samp.greedy | ~active),
+                                         all_greedy, mixed, None)
+            if logprobs:
+                # raw-distribution logprob of the emitted token, fused
+                # into the tick (logprobs is trace-static)
+                lsm = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                         axis=-1)
+                lps = jnp.take_along_axis(lsm, out[:, None], axis=-1)[:, 0]
+            else:
+                lps = jnp.zeros((out.shape[0],), jnp.float32)
         # free slots decode along on stale state but their feed token,
         # PRNG key, and position are all FROZEN here (one fused tick,
         # no per-field host dispatch): admission rewrites the whole
@@ -642,11 +644,6 @@ class ServeEngine:
             "host-observed gap between consecutive decode-tick "
             "completions within a busy streak",
             edges=self.TICK_GAP_EDGES_MS, window=16384)
-        self._m_collective = reg.histogram(
-            "serve_collective_ms",
-            "per-tick device->host token gather (the cross-device "
-            "collective + transfer cost of a sharded tick)",
-            edges=self.ITL_EDGES_MS)
         # mesh topology exported as set-gauges (reset() zeroes them, so
         # reset_stats re-sets; see _set_mesh_gauges)
         self._g_mesh_devices = reg.gauge(
@@ -1179,17 +1176,10 @@ class ServeEngine:
         tr = self.telemetry.tracer
         if tr:
             tr.begin("tick", "host_sync")
-        # device->host gather of the tick's tokens: on a sharded mesh this
-        # wait covers the tick's collectives + the cross-device transfer
-        t_c0 = time.perf_counter()
-        if tr:
-            tr.begin("tick", "collective", mesh=self._mesh_desc)
         toks = np.asarray(rec.toks)  # jaxlint: disable=host-sync-in-jit-path -- THE per-tick sync: double-buffered one tick behind under overlap
         lps = np.asarray(rec.lps) if self.logprobs else None  # jaxlint: disable=host-sync-in-jit-path -- same wait as toks (dispatched together)
         now = time.perf_counter()
-        self._m_collective.observe((now - t_c0) * 1e3)
         if tr:
-            tr.end("tick")  # collective
             tr.end("tick", slots=int(rec.active.sum()))
         # NB: with a prefill budget (or overlap), admission chunk work
         # dispatched ahead of this tick executes on the same device stream
@@ -1376,7 +1366,6 @@ class ServeEngine:
             "mesh": {
                 "shape": self._mesh_desc,
                 "devices": dict(self.plan.axis_sizes),
-                "collective_ms": self._m_collective.percentiles(),
             },
         }
         if self.prefix_cache is not None:

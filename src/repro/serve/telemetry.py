@@ -326,11 +326,10 @@ class MetricsRegistry:
 # rejects any event outside this vocabulary, so the schema below IS the
 # compatibility contract for trace consumers.
 SPAN_NAMES = frozenset({
-    # engine tick phases (track "tick"); "collective" nests inside
-    # "host_sync" and times the device->host token gather (the sharded
-    # tick's collective + transfer cost)
+    # engine tick phases (track "tick"), mirrored into the JAX profiler as
+    # "serve.<phase>" host spans while the tracer is on
     "tick", "plan", "chunk_dispatch", "decode_dispatch", "host_sync",
-    "collective", "retire",
+    "retire",
     # request lifecycle (track "slot<i>")
     "prefill", "decode",
     # replica lifecycle (coordinator track "replica<i>"): "replica" spans
@@ -367,7 +366,15 @@ class Tracer:
     counter samples append directly. ``export()`` renders the
     Chrome/Perfetto JSON (open it at ui.perfetto.dev or
     chrome://tracing).
+
+    Spans on the ``tick`` track also enter a
+    ``jax.profiler.TraceAnnotation`` named ``serve.<phase>``, so a
+    profiler trace taken while the engine runs shows the tick phases on
+    the same timeline as the device ops. (Request spans on the
+    ``slot<i>`` tracks outlive a tick and are not mirrored.)
     """
+
+    PROFILED_TRACK = "tick"
 
     def __init__(self, enabled: bool = True, max_events: int = 1 << 18,
                  on_event: Callable | None = None):
@@ -377,6 +384,10 @@ class Tracer:
         self._events: deque[tuple] = deque(maxlen=max_events)
         self._tids: OrderedDict[str, int] = OrderedDict()
         self._stacks: dict[str, list] = {}
+        self._annotation = None
+        if self.enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     def __bool__(self) -> bool:
         return self.enabled
@@ -413,8 +424,12 @@ class Tracer:
     def begin(self, track: str, name: str, **args):
         if not self.enabled:
             return
+        span = None
+        if track == self.PROFILED_TRACK:
+            span = self._annotation("serve." + name)
+            span.__enter__()
         self._stacks.setdefault(track, []).append(
-            (name, self._now_us(), args or None))
+            (name, self._now_us(), args or None, span))
 
     def end(self, track: str, **args):
         if not self.enabled:
@@ -422,7 +437,9 @@ class Tracer:
         stack = self._stacks.get(track)
         if not stack:
             return  # unbalanced end: drop rather than poison the serve loop
-        name, t0, a0 = stack.pop()
+        name, t0, a0, span = stack.pop()
+        if span is not None:
+            span.__exit__(None, None, None)
         merged = dict(a0 or {})
         merged.update(args)
         self._push(("X", name, self._tid(track), t0, self._now_us() - t0,
@@ -439,7 +456,7 @@ class Tracer:
         now = self._now_us()
         events = []
         for track, stack in self._stacks.items():
-            for name, t0, a0 in stack:
+            for name, t0, a0, _ in stack:
                 args = dict(a0 or {})
                 args["unterminated"] = True
                 events.append(("X", name, self._tid(track), t0, now - t0,

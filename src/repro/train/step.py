@@ -37,14 +37,16 @@ def make_loss_fn(model, cfg):
         targets = tokens[:, 1:]
         # the Pallas kernels have no VJP yet, so gradients take the XLA path
         logits, _, aux = model.apply(params, inp, mode="train", impl="xla")
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        mask = batch.get("loss_mask")
-        if mask is None:
-            loss = jnp.mean(nll)
-        else:
-            loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("loss"):
+            logits = logits.astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1)[..., 0]
+            mask = batch.get("loss_mask")
+            if mask is None:
+                loss = jnp.mean(nll)
+            else:
+                loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         metrics = {"ce_loss": loss}
         if aux:
             loss = (loss
@@ -95,11 +97,12 @@ def make_train_step(model, cfg, tcfg):
 
     def train_step(state: TrainState, batch):
         grads, metrics = compute_grads(state.params, batch)
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = schedule(state.step)
-        new_params, new_opt = adamw_update(
-            grads, state.opt, state.params, lr=lr, b1=tcfg.b1, b2=tcfg.b2,
-            weight_decay=tcfg.weight_decay)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+            lr = schedule(state.step)
+            new_params, new_opt = adamw_update(
+                grads, state.opt, state.params, lr=lr, b1=tcfg.b1,
+                b2=tcfg.b2, weight_decay=tcfg.weight_decay)
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)
         return TrainState(new_params, new_opt, state.step + 1), metrics
 
@@ -139,11 +142,12 @@ def make_manual_dp_train_step(model, cfg, tcfg, mesh, dp_axis: str = "data"):
         grads, metrics = jax.shard_map(
             sharded_grads, mesh=mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False)(state.params, batch)
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = schedule(state.step)
-        new_params, new_opt = adamw_update(
-            grads, state.opt, state.params, lr=lr, b1=tcfg.b1, b2=tcfg.b2,
-            weight_decay=tcfg.weight_decay)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+            lr = schedule(state.step)
+            new_params, new_opt = adamw_update(
+                grads, state.opt, state.params, lr=lr, b1=tcfg.b1,
+                b2=tcfg.b2, weight_decay=tcfg.weight_decay)
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)
         return TrainState(new_params, new_opt, state.step + 1), metrics
 

@@ -306,16 +306,23 @@ def test_engine_stats_compat_and_registry_view():
     assert eng.telemetry.watchdog.steady
 
 
-def test_tokens_bit_identical_with_and_without_tracing():
+def test_tokens_bit_identical_with_and_without_tracing(tmp_path):
     model, cfg, params = _setup(seed=2)
     sp = SamplingParams(temperature=0.9, top_k=12, seed=5)
     runs = []
-    for tel in (None, Telemetry(trace=True, memory=True, memory_every=1)):
-        eng = ServeEngine(model, cfg, params, slots=2, max_len=48,
-                          telemetry=tel)
-        for p in _prompts(cfg, [7, 13], seed=4):
-            eng.submit(p, 8, sampling=sp)
-        runs.append({o.rid: np.asarray(o.tokens) for o in eng.run()})
+    # the traced engine runs while a profiler captures, so its tick phases
+    # also enter profiler spans
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for tel in (None, Telemetry(trace=True, memory=True,
+                                    memory_every=1)):
+            eng = ServeEngine(model, cfg, params, slots=2, max_len=48,
+                              telemetry=tel)
+            for p in _prompts(cfg, [7, 13], seed=4):
+                eng.submit(p, 8, sampling=sp)
+            runs.append({o.rid: np.asarray(o.tokens) for o in eng.run()})
+    finally:
+        jax.profiler.stop_trace()
     for rid in runs[0]:
         np.testing.assert_array_equal(runs[0][rid], runs[1][rid])
 

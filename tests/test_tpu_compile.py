@@ -7,6 +7,7 @@ use, or a program larger than the chip's memory. The topology is described
 inside a fixture, so importing this file loads no TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -131,6 +132,12 @@ def test_engine_decode_tick_compiles(one_chip, model_shapes):
             params, s((SLOTS, 1, 1), jnp.int32), s((SLOTS,), jnp.int32),
             keys, samp, caches, s((SLOTS,), jnp.bool_)).compile()
     _fits_one_chip(compiled)
+    # the layers' named scopes survive the chip's compiler in the ops'
+    # metadata, where a device trace reads them
+    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    for scope in ("mixer", "sketch", "fold", "ffn", "logits", "sampler"):
+        assert any(f"/{scope}/" in p or f"({scope})" in p
+                   for p in paths), scope
 
 
 def test_engine_prefill_chunk_compiles(one_chip, model_shapes):
