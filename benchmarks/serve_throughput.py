@@ -183,10 +183,12 @@ def _tick_vs_roofline(eng, cfg, *, plen, ticks=32):
     eng.run()
     flops = bts = 0.0
     try:
-        ca = eng._decode.lower(
-            eng.params, eng._slot_tokens, eng._slot_pos, eng._slot_keys,
-            eng._slot_samp, eng._slot_caches,
-            jnp.ones((eng.slots,), bool)).compile().cost_analysis()
+        # the jitted tick itself: the engine's wrapper of it has no .lower
+        with eng.plan.activation_context():
+            ca = eng._decode_tick._inner.lower(
+                eng.params, eng._slot_tokens, eng._slot_pos, eng._slot_keys,
+                eng._slot_samp, eng._slot_caches,
+                jnp.ones((eng.slots,), bool)).compile().cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}
         flops = float(ca.get("flops", 0.0))

@@ -44,12 +44,16 @@ def init_polysketch_cache(batch, n_kv_heads, head_dim, r, block_size,
 
 def polysketch_decode_step(cache: PolysketchCache, qm, km, q, k, v, *,
                            degree: int, scale: float,
-                           local_exact: bool = True):
+                           local_exact: bool = True, fold_due=None):
     """One decode step.
 
     qm: (B, Hq, r)  sketched query (input pre-scaled by sqrt(scale))
     km: (B, Hkv, r) sketched key
     q:  (B, Hq, h)  post-LN query;  k, v: (B, Hkv, h)
+    fold_due: optional unbatched scalar bool gating the fold: true when
+      some cache of the batch this step is vmapped over completes its
+      block (`polysketch_fold_due`); None folds on this cache's own
+      position alone.
     Returns (out (B, Hq, h), new_cache).
     """
     bsz, hq, hd = q.shape
@@ -72,9 +76,12 @@ def polysketch_decode_step(cache: PolysketchCache, qm, km, q, k, v, *,
         w = jnp.einsum("bngr,bnsr->bngs", qmg, mbuf) ** 2
     valid = (jnp.arange(blk) <= fill)[None, None, None, :]
     w = jnp.where(valid, w, 0.0)
-    ones = jnp.ones((*vbuf.shape[:-1], 1), f32)
-    vv = jnp.concatenate([vbuf.astype(f32), ones], axis=-1)   # (B,Hkv,blk,h+1)
-    local = jnp.einsum("bngs,bnsd->bngd", w, vv)
+
+    def values_and_ones():                                     # (B,Hkv,blk,h+1)
+        ones = jnp.ones((*vbuf.shape[:-1], 1), f32)
+        return jnp.concatenate([vbuf.astype(f32), ones], axis=-1)
+
+    local = jnp.einsum("bngs,bnsd->bngd", w, values_and_ones())
 
     # --- sketched prefix (folded blocks) ---
     qf = self_kron(qmg)                                        # (B,Hkv,g,r^2)
@@ -85,17 +92,31 @@ def polysketch_decode_step(cache: PolysketchCache, qm, km, q, k, v, *,
 
     # --- fold the block into the prefix state when it completes ---
     def fold(z):
+        # [v, 1] is built again inside the branch: taken from outside it
+        # would be an operand of the cond, written out on every step
         kf = self_kron(mbuf)                                   # (B,Hkv,blk,r^2)
-        return z + jnp.einsum("bnsf,bnsd->bnfd", kf, vv)
+        return z + jnp.einsum("bnsf,bnsd->bnfd", kf, values_and_ones())
 
-    # the scope holds the cond itself: under the serve engine's vmap the
-    # cond lowers to a select over both branches, and that select is fold
-    # work too
+    # the scope holds the cond itself. Vmapped over serve slots, a cond on
+    # this cache's own `fill` is batched and lowers to a select that folds
+    # every slot on every step; an unbatched `fold_due` keeps it a real
+    # branch, and the due step selects per slot exactly as that select did
+    completes = fill == blk - 1
+    pred = completes if fold_due is None else fold_due
     with jax.named_scope("fold"):
-        z = jax.lax.cond(fill == blk - 1, fold, lambda z: z, cache.z)
+        z = jax.lax.cond(pred, lambda z: jnp.where(completes, fold(z), z),
+                         lambda z: z, cache.z)
     new_cache = PolysketchCache(z=z, kbuf=kbuf, vbuf=vbuf, mbuf=mbuf,
                                 pos=cache.pos + 1)
     return out.astype(v.dtype), new_cache
+
+
+def polysketch_fold_due(cache: PolysketchCache):
+    """Scalar bool: the next decode step completes a block, and folds it,
+    at some position of `cache.pos` (any leading stacking: slots,
+    layers)."""
+    blk = cache.kbuf.shape[-2]
+    return jnp.any(jnp.mod(cache.pos, blk) == blk - 1)
 
 
 class RecurrentCache(NamedTuple):

@@ -23,7 +23,8 @@ the prefix cache never branch on model family or mechanism name:
 
   - ``DecodeState``: the model-level facade (built by ``model_zoo``)
     exposing ``init / init_slot / prefill / resume / decode_step /
-    snapshot / restore / serialize / deserialize`` plus the slot helpers.
+    fold_due / snapshot / restore / serialize / deserialize`` plus the
+    slot helpers.
     Everything the serve stack needs, independent of family.
 
 Snapshot granularity semantics (per kind, composed over a model's kinds):
@@ -62,6 +63,7 @@ class NodeOps(NamedTuple):
     granularity: str | None          # "block" | "token" | None
     snapshot: Callable | None        # node -> constant-size snapshot pytree
     restore: Callable | None         # (fresh_node, snapshot, n_tokens) -> node
+    fold_due: Callable | None = None  # node -> scalar bool; None: never folds
 
 
 def _psk_snapshot(node: dec.PolysketchCache):
@@ -102,7 +104,8 @@ def _ring_restore(fresh: dec.RingKVCache, snap: dec.RingKVCache, n_tokens):
 
 
 NODE_OPS: dict[type, NodeOps] = {
-    dec.PolysketchCache: NodeOps("block", _psk_snapshot, _psk_restore),
+    dec.PolysketchCache: NodeOps("block", _psk_snapshot, _psk_restore,
+                                 dec.polysketch_fold_due),
     dec.RecurrentCache: NodeOps("token", _rec_snapshot, _rec_restore),
     dec.RingKVCache: NodeOps("token", _ring_snapshot, _ring_restore),
     dec.KVCache: NodeOps(None, None, None),
@@ -475,14 +478,29 @@ class DecodeState:
                                        positions=positions)
         return logits[:, -1], state
 
-    def decode_step(self, params, tok, pos, state):
+    def decode_step(self, params, tok, pos, state, fold_due=None):
         """tok (B, 1) at position `pos` (scalar; shared across the batch)
-        -> (logits (B, V), state)."""
+        -> (logits (B, V), state). `fold_due`, one unbatched predicate
+        (`fold_due` of every state the step is vmapped over), gates the
+        block folds; None folds on each node's own position."""
         positions = jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,))[:1]
         logits, state, _ = self._apply(params, {"tokens": tok},
                                        mode="decode", cache=state,
-                                       positions=positions)
+                                       positions=positions,
+                                       fold_due=fold_due)
         return logits[:, -1], state
+
+    @staticmethod
+    def fold_due(state):
+        """Scalar bool: the next decode step folds a block somewhere in
+        `state`, a cache pytree of any stacking (slots, layers); a
+        constant False when no node kind folds."""
+        due = jnp.asarray(False)
+        for node in jax.tree_util.tree_leaves(state, is_leaf=is_state_node):
+            fn = NODE_OPS[type(node)].fold_due
+            if fn is not None:
+                due = due | fn(node)
+        return due
 
     # -- snapshots ---------------------------------------------------------
 

@@ -103,7 +103,8 @@ def init_cache(params, cfg, kind: str, batch: int, max_len: int, dtype):
 
 
 def attention_apply(params, cfg, x, *, kind: str, positions, mode: str,
-                    cache=None, memory=None, impl: str | None = None):
+                    cache=None, memory=None, impl: str | None = None,
+                    fold_due=None):
     """Returns (y (B,S,D), new_cache_or_None)."""
     scale = cfg.attn_scale
     mech = cfg.attention if kind == "attn" else "softmax"
@@ -123,7 +124,7 @@ def attention_apply(params, cfg, x, *, kind: str, positions, mode: str,
             km = sketch_half(params["sketch"], k * rt, cfg.poly_degree, cfg.learned_sketch)
             y, cache = dec.polysketch_decode_step(
                 cache, qm, km, q, k, v, degree=cfg.poly_degree, scale=scale,
-                local_exact=cfg.local_exact)
+                local_exact=cfg.local_exact, fold_due=fold_due)
         elif skind == "poly_kv":
             q, k = _poly_ln(params, q, k)
             y, cache = dec.poly_kv_decode_step(cache, q, k, v,

@@ -52,10 +52,11 @@ def build_model(cfg) -> Model:
         return tfm.lm_init(key, cfg)
 
     def apply(params, batch, *, mode="train", cache=None, impl=None,
-              positions=None):
+              positions=None, fold_due=None):
         return tfm.lm_apply(params, cfg, batch["tokens"], mode=mode,
                             cache=cache, positions=positions,
-                            image_embeds=batch.get("image_embeds"), impl=impl)
+                            image_embeds=batch.get("image_embeds"), impl=impl,
+                            fold_due=fold_due)
 
     def init_cache(params, batch_size, max_len):
         return tfm.lm_init_cache(params, cfg, batch_size, max_len)

@@ -109,7 +109,7 @@ def lm_init(key, cfg):
 
 
 def _apply_block(bp, cfg, h, mixer_kind, ffn_kind, *, positions, mode, cache,
-                 impl):
+                 impl, fold_due=None):
     h = shard_act(h, "batch", "seq", "embed")
     # the scope names ("mixer", "ffn", and "embed", "logits" in lm_apply)
     # label the compiled ops in a device trace; tests pin the vocabulary
@@ -118,7 +118,7 @@ def _apply_block(bp, cfg, h, mixer_kind, ffn_kind, *, positions, mode, cache,
         if mixer_kind in ("attn", "local_attn"):
             y, new_cache = attn.attention_apply(
                 bp["mixer"], cfg, hn, kind=mixer_kind, positions=positions,
-                mode=mode, cache=cache, impl=impl)
+                mode=mode, cache=cache, impl=impl, fold_due=fold_due)
         elif mixer_kind == "rglru":
             y, new_cache = rglru_mod.rglru_apply(bp["mixer"], cfg, hn,
                                                  mode=mode, cache=cache)
@@ -152,8 +152,10 @@ def _add_aux(acc, aux):
 
 
 def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
-             positions=None, image_embeds=None, impl: str | None = None):
-    """tokens: (B, S) int32 (S==1 for decode).
+             positions=None, image_embeds=None, impl: str | None = None,
+             fold_due=None):
+    """tokens: (B, S) int32 (S==1 for decode). `fold_due` gates the
+    polysketch decode step's block fold (core.decode.polysketch_decode_step).
 
     Returns (logits (B, S, V), new_cache, aux) — cache is None in train mode.
     """
@@ -181,7 +183,8 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
             c_in = None if gcache is None else gcache[f"block{bi}"]
             hh, nc, aux = _apply_block(gp[f"block{bi}"], cfg, hh, mk, fk,
                                        positions=positions, mode=mode,
-                                       cache=c_in, impl=impl)
+                                       cache=c_in, impl=impl,
+                                       fold_due=fold_due)
             new_caches[f"block{bi}"] = nc
             auxc = _add_aux(auxc, aux)
         return (hh, auxc), new_caches
@@ -226,7 +229,8 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
             c_in = None if cache is None else cache["tail"][f"block{bi}"]
             h, nc, aux = _apply_block(params["tail"][f"block{bi}"], cfg, h, mk,
                                       fk, positions=positions, mode=mode,
-                                      cache=c_in, impl=impl)
+                                      cache=c_in, impl=impl,
+                                      fold_due=fold_due)
             new_tail[f"block{bi}"] = nc
             aux_total = _add_aux(aux_total, aux)
 

@@ -157,11 +157,21 @@ def generate(model, cfg, params, prompt: jax.Array, steps: int, *,
 def make_decode_tick(state, plan: ServePlan, logprobs: bool):
     """The body of the engine's jitted decode tick over every slot:
     decode_all(params, toks, pos, keys, samp, caches, active) ->
-    (tokens, logprobs, toks, pos, keys, caches). Trace it inside
-    `plan.activation_context()`."""
+    (tokens, logprobs, toks, pos, keys, caches, fold_due). Trace it inside
+    `plan.activation_context()`.
 
-    def decode_one(params, tok, pos, cache):
-        logits, cache = state.decode_step(params, tok, pos, cache)
+    `fold_due` is one scalar for the whole tick: some slot's state
+    completes a block, and folds it, on this tick (`state.fold_due`; False
+    for state kinds that never fold). It is read from the slot-stacked
+    caches' own positions, not from `pos`: a free slot's `pos` is frozen
+    while its stale state decodes, and folds, along. It is computed
+    outside the slot vmap and passed in unbatched, so the fold stays a
+    real conditional that runs on the few ticks that need it; a predicate
+    read per slot inside the vmap is batched, and lowers to a select that
+    folds every slot on every tick."""
+
+    def decode_one(params, tok, pos, cache, fold_due):
+        logits, cache = state.decode_step(params, tok, pos, cache, fold_due)
         return logits[0], cache
 
     def decode_all(params, toks, pos, keys, samp, caches, active):
@@ -175,8 +185,9 @@ def make_decode_tick(state, plan: ServePlan, logprobs: bool):
         # slots never consume their PRNG stream, so the fast path
         # leaving keys un-split is not observable in any request's
         # tokens.
-        logits, caches = jax.vmap(decode_one, in_axes=(None, 0, 0, 0))(
-            params, toks, pos, caches)
+        fold_due = state.fold_due(caches)
+        logits, caches = jax.vmap(decode_one, in_axes=(None, 0, 0, 0, None))(
+            params, toks, pos, caches, fold_due)
         # gather the vocab dim before softmax/argmax: the reductions
         # below must see identically-ordered operands on every mesh
         logits = plan.constrain_logits(logits)
@@ -211,7 +222,7 @@ def make_decode_tick(state, plan: ServePlan, logprobs: bool):
                              toks)
         new_keys = jnp.where(active[:, None], new_keys, keys)
         new_pos = jnp.where(active, pos + 1, pos)
-        return out, lps, new_toks, new_pos, new_keys, caches
+        return out, lps, new_toks, new_pos, new_keys, caches, fold_due
 
     return decode_all
 
@@ -287,6 +298,7 @@ class _TickRecord:
     dispatch and sync drops its speculative token via the rid mismatch."""
     toks: object                 # (slots,) device array
     lps: object
+    fold: object                 # () device bool: the tick folded a block
     active: np.ndarray           # dispatch-time decoding mask
     rids: list[int | None]
     firsts: list[tuple]          # (slot, rid, tok_dev, lp_dev) admissions
@@ -374,6 +386,7 @@ class ServeEngine:
         self._next_rid = 0
         self._slots = [_Slot() for _ in range(slots)]
         self._pending: _TickRecord | None = None  # overlap double buffer
+        self._tick_fold = None     # the last tick's fold flag (see _decode)
         self.telemetry = telemetry if telemetry is not None else Telemetry()
 
         state = self.state
@@ -557,11 +570,12 @@ class ServeEngine:
                           rep),
             out_shardings=(cacheS_sh, tok_sh, pos_sh, keys_sh, samp_sh,
                            rep, rep)))
-        self._decode = wrap(jax.jit(
+        self._decode_tick = wrap(jax.jit(
             decode_all, donate_argnums=(5,),
             in_shardings=(param_sh, tok_sh, pos_sh, keys_sh, samp_sh,
                           cacheS_sh, rep),
-            out_shardings=(rep, rep, tok_sh, pos_sh, keys_sh, cacheS_sh)))
+            out_shardings=(rep, rep, tok_sh, pos_sh, keys_sh, cacheS_sh,
+                           rep)))
         self._replay = wrap(jax.jit(
             replay_tokens,
             in_shardings=(param_sh, rep, rep, cache1_sh),
@@ -581,7 +595,7 @@ class ServeEngine:
                            ("fresh_slot", self._fresh_slot),
                            ("restore", self._restore),
                            ("install_slot", self._install_slot),
-                           ("decode", self._decode),
+                           ("decode", self._decode_tick),
                            ("replay", self._replay),
                            ("install_restored", self._install_restored)):
             self.telemetry.watchdog.register(_name, _fn)
@@ -620,6 +634,9 @@ class ServeEngine:
             "installed requests with non-greedy sampling")
         self._m_ticks = reg.counter(
             "serve_decode_ticks_total", "jitted decode ticks dispatched")
+        self._m_fold_ticks = reg.counter(
+            "serve_fold_ticks_total",
+            "decode ticks on which some slot folded a completed block")
         self._m_tokens = reg.counter(
             "serve_tokens_total", "tokens emitted (first + decode)")
         self._m_finished = reg.counter(
@@ -1165,7 +1182,18 @@ class ServeEngine:
         for si, slot in enumerate(self._slots):
             if active[si]:
                 slot.ticks += 1
-        return _TickRecord(toks, lps, active, rids, firsts, t0)
+        return _TickRecord(toks, lps, self._tick_fold, active, rids, firsts,
+                           t0)
+
+    def _decode(self, params, toks, pos, keys, samp, caches, active):
+        """Run the jitted decode tick and return its first six outputs
+        (tokens, logprobs, then the slots' feed tokens, positions, keys
+        and caches); its fold flag is left in `self._tick_fold` for the
+        tick record, so whatever wraps this method keeps the six-output
+        form. `caches` is donated."""
+        *outs, self._tick_fold = self._decode_tick(params, toks, pos, keys,
+                                                   samp, caches, active)
+        return tuple(outs)
 
     def _sync_record(self, rec: _TickRecord, done):
         """Sync one tick record's tokens to the host and account them.
@@ -1178,9 +1206,12 @@ class ServeEngine:
             tr.begin("tick", "host_sync")
         toks = np.asarray(rec.toks)  # jaxlint: disable=host-sync-in-jit-path -- THE per-tick sync: double-buffered one tick behind under overlap
         lps = np.asarray(rec.lps) if self.logprobs else None  # jaxlint: disable=host-sync-in-jit-path -- same wait as toks (dispatched together)
+        fold = bool(rec.fold)  # jaxlint: disable=host-sync-in-jit-path -- same wait as toks (dispatched together)
         now = time.perf_counter()
+        if fold:
+            self._m_fold_ticks.inc()
         if tr:
-            tr.end("tick", slots=int(rec.active.sum()))
+            tr.end("tick", slots=int(rec.active.sum()), fold=fold)
         # NB: with a prefill budget (or overlap), admission chunk work
         # dispatched ahead of this tick executes on the same device stream
         # and is absorbed into this wait — decode_s measures the decode
@@ -1341,6 +1372,7 @@ class ServeEngine:
             "sampled_requests": int(self._m_sampled.value),
             "recovered": int(self._m_recovered.value),
             "decode_steps": int(self._m_ticks.value),
+            "fold_ticks": int(self._m_fold_ticks.value),
             "prefill_s": self._m_prefill_s.value,
             "decode_s": decode_s,
             "decode_tok_per_s": (decode_tokens / decode_s

@@ -8,8 +8,12 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core.decode import PolysketchCache
 from repro.models import build_model
-from repro.serve import SamplingParams, ServeEngine, generate
+from repro.serve import SamplingParams, ServeEngine, Telemetry, generate
+from repro.serve.engine import make_decode_tick
+from repro.serve.plan import ServePlan
+from repro.serve.sampling import init_slot_keys, init_slot_sampling
 
 
 def _setup(seed=0, **overrides):
@@ -298,3 +302,107 @@ def test_engine_accounting():
     for o in outs:
         assert 0 < o.ttft_s <= o.latency_s
         assert o.decode_steps == len(o.tokens) - 1
+
+
+class _SelectFold:
+    """The state protocol with every slot folding on its own position:
+    under the tick's slot vmap that predicate is batched, so the fold
+    lowers to a select computed for every slot on every tick."""
+
+    def __init__(self, state):
+        self.state = state
+
+    def decode_step(self, params, tok, pos, cache, fold_due):
+        del fold_due
+        return self.state.decode_step(params, tok, pos, cache)
+
+    def fold_due(self, caches):
+        return self.state.fold_due(caches)
+
+
+def _psk_nodes(caches):
+    return [n for n in jax.tree_util.tree_leaves(
+        caches, is_leaf=lambda x: isinstance(x, PolysketchCache))
+        if isinstance(n, PolysketchCache)]
+
+
+FOLD_CASES = pytest.mark.parametrize("cache_pos,active", [
+    ([3, 20, 33, 40], [1, 1, 1, 1]),    # no slot completes a block
+    ([15, 20, 33, 40], [1, 1, 1, 1]),   # exactly one does
+    ([15, 31, 33, 47], [1, 1, 1, 1]),   # several in the same tick
+    ([3, 20, 31, 40], [1, 1, 0, 1]),    # only a free slot does
+], ids=["none", "one", "several", "free-only"])
+
+
+@FOLD_CASES
+def test_gated_fold_tick_matches_select_fold(cache_pos, active):
+    """The engine's tick, folding under one unbatched predicate, is bit-
+    identical to the same tick folding by a per-slot select: tokens, log-
+    probabilities and every cache leaf."""
+    _check_gated_fold(cache_pos, active)
+
+
+@FOLD_CASES
+def test_gated_fold_tick_matches_select_fold_hybrid(cache_pos, active):
+    """The same in a composite of an RG-LRU and a polysketch layer, whose
+    polysketch nodes alone decide the fold."""
+    _check_gated_fold(cache_pos, active, block_pattern=("rglru", "attn"))
+
+
+def _check_gated_fold(cache_pos, active, **overrides):
+    model, cfg, params = _setup(seed=5, **overrides)
+    st, plan, blk = model.state, ServePlan.single_device(), cfg.lt_block_size
+    slots = len(cache_pos)
+    caches = st.broadcast_slots(st.init_slot(params, 64), slots)
+    for si, prompt in enumerate(_prompts(cfg, cache_pos, seed=5)):
+        _, one = st.prefill(params, prompt[None], st.init_slot(params, 64))
+        caches = st.slot_scatter(caches, one, si)
+    rng = np.random.default_rng(5)
+    args = (params,
+            jnp.asarray(rng.integers(0, cfg.vocab_size, (slots, 1, 1)),
+                        jnp.int32),
+            jnp.asarray(cache_pos, jnp.int32), init_slot_keys(slots),
+            init_slot_sampling(slots), caches,
+            jnp.asarray(active, bool))
+    with plan.activation_context():
+        got = jax.jit(make_decode_tick(st, plan, logprobs=True))(*args)
+        want = jax.jit(make_decode_tick(_SelectFold(st), plan,
+                                        logprobs=True))(*args)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)
+    completes = [n % blk == blk - 1 for n in cache_pos]
+    assert bool(got[6]) == any(completes)
+    # the tick folded exactly the slots whose block completed
+    for before, after in zip(_psk_nodes(caches), _psk_nodes(got[5])):
+        for si, done in enumerate(completes):
+            changed = not np.array_equal(before.z[si], after.z[si])
+            assert changed == done, (si, done)
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_fold_ticks_count_the_ticks_that_fold(overlap):
+    """stats()["fold_ticks"] and the tick event's `fold` flag report the
+    ticks on which some slot's state, free slots' included, completed a
+    block."""
+    model, cfg, params = _setup(seed=4)
+    blk = cfg.lt_block_size
+    eng = ServeEngine(model, cfg, params, slots=3, max_len=64,
+                      overlap=overlap, telemetry=Telemetry(trace=True))
+    tick, due = eng._decode, []
+
+    def spy(*args):
+        due.append(any(np.any(np.asarray(n.pos) % blk == blk - 1)
+                       for n in _psk_nodes(args[5])))
+        return tick(*args)
+
+    eng._decode = spy
+    for p, n in zip(_prompts(cfg, [5, 14, 30, 9], seed=4), [20, 6, 12, 18]):
+        eng.submit(p, n)
+    eng.run()
+    st = eng.stats()
+    assert st["decode_steps"] == len(due)
+    assert 0 < st["fold_ticks"] == sum(due) < len(due)
+    flags = [ev[5]["fold"] for ev in eng.telemetry.tracer._events
+             if ev[0] == "X" and ev[1] == "host_sync"]
+    assert flags == due

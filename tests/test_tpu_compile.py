@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.configs.gpt2_paper import GPT2_SMALL_SOFTMAX
 from repro.kernels.lt_mult import lt_mult_pallas
 from repro.kernels.poly_flash import poly_flash_pallas
 from repro.kernels.polysketch_causal import polysketch_causal_pallas
@@ -60,16 +61,20 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def model_shapes(one_chip):
-    """gpt2s-polysketch at published widths: model, state protocol,
-    abstract parameters placed on the described chip, and a placement."""
-    cfg = get_config("gpt2s-polysketch")
+def _published(cfg, one_chip):
+    """A config at published widths: model, state protocol, abstract
+    parameters placed on the described chip, and a placement."""
     model = build_model(cfg)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0])
     mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1),
                 ("data", "model"))
     return cfg, model, _on(params, one_chip), ServePlan.from_mesh(mesh)
+
+
+@pytest.fixture(scope="module")
+def model_shapes(one_chip):
+    """gpt2s-polysketch at published widths."""
+    return _published(get_config("gpt2s-polysketch"), one_chip)
 
 
 def _on(tree, sharding):
@@ -117,8 +122,9 @@ def test_lt_mult_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_engine_decode_tick_compiles(one_chip, model_shapes):
-    cfg, model, params, plan = model_shapes
+def _compile_tick(one_chip, shapes):
+    """The engine's decode tick over SLOTS slots, compiled for the chip."""
+    cfg, model, params, plan = shapes
     st = model.state
     caches = _on(jax.eval_shape(
         lambda p: st.broadcast_slots(st.init_slot(p, MAX_LEN), SLOTS),
@@ -128,16 +134,40 @@ def test_engine_decode_tick_compiles(one_chip, model_shapes):
     samp = _on(jax.eval_shape(lambda: init_slot_sampling(SLOTS)), one_chip)
     tick = make_decode_tick(st, plan, logprobs=True)
     with plan.activation_context():
-        compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        return jax.jit(tick, donate_argnums=(5,)).lower(
             params, s((SLOTS, 1, 1), jnp.int32), s((SLOTS,), jnp.int32),
             keys, samp, caches, s((SLOTS,), jnp.bool_)).compile()
+
+
+def _conditional_paths(hlo_text):
+    """op_name of every conditional instruction in a compiled program."""
+    return [m.group(1) for line in hlo_text.splitlines()
+            if " conditional(" in line
+            for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+
+def test_engine_decode_tick_compiles(one_chip, model_shapes):
+    compiled = _compile_tick(one_chip, model_shapes)
     _fits_one_chip(compiled)
+    text = compiled.as_text()
     # the layers' named scopes survive the chip's compiler in the ops'
     # metadata, where a device trace reads them
-    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    paths = re.findall(r'op_name="([^"]*)"', text)
     for scope in ("mixer", "sketch", "fold", "ffn", "logits", "sampler"):
         assert any(f"/{scope}/" in p or f"({scope})" in p
                    for p in paths), scope
+    # the block fold stays a branch under the slot vmap, taken on the
+    # ticks where some slot completes a block, and not a select that
+    # folds every slot on every tick
+    assert any("/fold/" in p for p in _conditional_paths(text))
+
+
+def test_engine_softmax_decode_tick_compiles(one_chip):
+    compiled = _compile_tick(one_chip,
+                             _published(GPT2_SMALL_SOFTMAX, one_chip))
+    _fits_one_chip(compiled)
+    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    assert not any("/fold/" in p for p in paths)
 
 
 def test_engine_prefill_chunk_compiles(one_chip, model_shapes):
