@@ -10,8 +10,8 @@ from repro.core.linear_attention import (
 )
 from repro.core.decode import (
     PolysketchCache, KVCache, RecurrentCache, init_polysketch_cache,
-    polysketch_decode_step, polysketch_prefill, init_kv_cache,
-    kv_decode_step, kv_ring_decode_step, poly_kv_decode_step,
+    polysketch_decode_step, polysketch_fold, polysketch_prefill,
+    init_kv_cache, kv_decode_step, kv_ring_decode_step, poly_kv_decode_step,
     broadcast_slot_caches, slot_scatter, slot_gather,
 )
 from repro.core.state import (
@@ -25,7 +25,8 @@ __all__ = [
     "qk_layernorm", "poly_attention_full", "softmax_attention_full",
     "block_causal_linear_attention", "noncausal_linear_attention",
     "PolysketchCache", "KVCache", "RecurrentCache", "init_polysketch_cache",
-    "polysketch_decode_step", "polysketch_prefill", "init_kv_cache",
+    "polysketch_decode_step", "polysketch_fold", "polysketch_prefill",
+    "init_kv_cache",
     "kv_decode_step", "kv_ring_decode_step", "poly_kv_decode_step",
     "broadcast_slot_caches", "slot_scatter", "slot_gather",
     "DecodeState", "StateSpec", "register_state", "get_spec", "state_kinds",

@@ -8,9 +8,16 @@ The polysketch decode step is *bit-equivalent in semantics* to the training
 block algorithm (linear_attention.block_causal_linear_attention): a token
 attends exactly (degree-p polynomial weights) to tokens in its own block so
 far, and through the sketched prefix state to all earlier, completed blocks.
-When the buffer fills, the whole block is folded into the prefix state.
+When a step fills the buffer, `polysketch_fold` folds the whole block into
+the prefix state before the next step.
 
 All caches here are per-layer pytrees; the model stacks them over layers.
+A decode step takes either one layer's node or the whole layer-stacked
+node with the `layer` to step: it then writes the token's rows into the
+stack in place and reads that layer read-only, so the model's layer loop
+carries the stack instead of slicing it out and writing it back whole.
+A node's `pos` is one position shared by the batch, or one per batch row
+(the serve engine's slots, each at its own depth).
 """
 from __future__ import annotations
 
@@ -22,12 +29,67 @@ import jax.numpy as jnp
 from repro.utils import self_kron
 
 
+def layer_node(node, layer):
+    """One layer of a layer-stacked node (the node itself when `layer` is
+    None)."""
+    if layer is None:
+        return node
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False),
+        node)
+
+
+def put_layer_node(stack, layer, node):
+    """Write one layer's node into a layer-stacked node (the whole-node
+    write of kinds whose every leaf changes with each token)."""
+    if layer is None:
+        return node
+    return jax.tree_util.tree_map(
+        lambda full, one: jax.lax.dynamic_update_index_in_dim(
+            full, one.astype(full.dtype), layer, 0), stack, node)
+
+
+def _row_pos(node, layer, bsz):
+    """(B,) position of every batch row of `node` at `layer`."""
+    return jnp.broadcast_to(layer_node(node.pos, layer), (bsz,))
+
+
+def _write_rows(node, layer, row, **rows):
+    """Write one token's rows in place: buffer `name` (B, Hkv, S, .) of
+    `node` takes rows[name] (B, Hkv, .) at sequence row row[b] of batch row
+    b, at `layer` of a layer-stacked node. One dynamic update per batch
+    row: an out-of-range row clamps as it always did, and the updates
+    take the buffer's layout as it is (one scatter of every row makes the
+    TPU's compiler relay the whole stack around it)."""
+    at = () if layer is None else (layer,)
+    lead = (1,) * len(at)
+
+    def write(buf, val):
+        val = val.astype(buf.dtype)
+        for b in range(row.shape[0]):
+            buf = jax.lax.dynamic_update_slice(
+                buf, val[b].reshape(lead + (1, val.shape[1], 1, val.shape[2])),
+                (*at, b, 0, row[b], 0))
+        return buf
+    return node._replace(**{name: write(getattr(node, name), val)
+                            for name, val in rows.items()})
+
+
+def _advance(node, layer):
+    """`node` with its position at `layer` one token further."""
+    if layer is None:
+        return node._replace(pos=node.pos + 1)
+    pos = layer_node(node.pos, layer) + 1
+    return node._replace(
+        pos=jax.lax.dynamic_update_index_in_dim(node.pos, pos, layer, 0))
+
+
 class PolysketchCache(NamedTuple):
     z: jax.Array      # (B, Hkv, r^2, h+1) f32 prefix state over folded blocks
     kbuf: jax.Array   # (B, Hkv, b, h)     raw keys, current partial block
-    vbuf: jax.Array   # (B, Hkv, b, h)
+    vbuf: jax.Array   # (B, Hkv, b, h+1)   raw values and a column of ones
     mbuf: jax.Array   # (B, Hkv, b, r)     sketched keys, current partial block
-    pos: jax.Array    # ()                 int32 tokens consumed so far
+    pos: jax.Array    # () or (B,)         int32 tokens consumed so far
 
 
 def init_polysketch_cache(batch, n_kv_heads, head_dim, r, block_size,
@@ -36,7 +98,8 @@ def init_polysketch_cache(batch, n_kv_heads, head_dim, r, block_size,
     return PolysketchCache(
         z=jnp.zeros((batch, n_kv_heads, r * r, head_dim + 1), jnp.float32),
         kbuf=jnp.zeros((batch, n_kv_heads, b, head_dim), dtype),
-        vbuf=jnp.zeros((batch, n_kv_heads, b, head_dim), dtype),
+        vbuf=jnp.concatenate([jnp.zeros((batch, n_kv_heads, b, head_dim), dtype),
+                              jnp.ones((batch, n_kv_heads, b, 1), dtype)], -1),
         mbuf=jnp.zeros((batch, n_kv_heads, b, r), jnp.float32),
         pos=jnp.zeros((), jnp.int32),
     )
@@ -44,28 +107,28 @@ def init_polysketch_cache(batch, n_kv_heads, head_dim, r, block_size,
 
 def polysketch_decode_step(cache: PolysketchCache, qm, km, q, k, v, *,
                            degree: int, scale: float,
-                           local_exact: bool = True, fold_due=None):
-    """One decode step.
+                           local_exact: bool = True, layer=None):
+    """One decode step: write the token into the buffers, attend, advance.
 
     qm: (B, Hq, r)  sketched query (input pre-scaled by sqrt(scale))
     km: (B, Hkv, r) sketched key
     q:  (B, Hq, h)  post-LN query;  k, v: (B, Hkv, h)
-    fold_due: optional unbatched scalar bool gating the fold: true when
-      some cache of the batch this step is vmapped over completes its
-      block (`polysketch_fold_due`); None folds on this cache's own
-      position alone.
+    layer: None steps `cache` itself; else `cache` is the layer-stacked
+      node (leaves (L, B, ...)) and the step writes that layer in place.
+    A block the step completes stays in the buffers until
+    `polysketch_fold` folds it into z, before the next step.
     Returns (out (B, Hq, h), new_cache).
     """
     bsz, hq, hd = q.shape
     hkv = k.shape[1]
     g = hq // hkv
-    blk = cache.kbuf.shape[2]
-    fill = jnp.mod(cache.pos, blk)  # slot for the incoming token
-
+    blk = cache.kbuf.shape[-2]
+    fill = jnp.mod(_row_pos(cache, layer, bsz), blk)  # row of the token
     f32 = jnp.float32
-    kbuf = jax.lax.dynamic_update_index_in_dim(cache.kbuf, k.astype(cache.kbuf.dtype), fill, axis=2)
-    vbuf = jax.lax.dynamic_update_index_in_dim(cache.vbuf, v.astype(cache.vbuf.dtype), fill, axis=2)
-    mbuf = jax.lax.dynamic_update_index_in_dim(cache.mbuf, km.astype(f32), fill, axis=2)
+    # write the token's rows, then read this layer's buffers
+    cache = _write_rows(cache, layer, fill, kbuf=k, vbuf=v, mbuf=km.astype(f32))
+    kbuf, vbuf, mbuf = (layer_node(x, layer)
+                        for x in (cache.kbuf, cache.vbuf, cache.mbuf))
 
     # --- local (within current partial block) attention weights ---
     qg = q.reshape(bsz, hkv, g, hd).astype(f32)
@@ -74,41 +137,54 @@ def polysketch_decode_step(cache: PolysketchCache, qm, km, q, k, v, *,
         w = (jnp.einsum("bngh,bnsh->bngs", qg, kbuf.astype(f32)) * scale) ** degree
     else:
         w = jnp.einsum("bngr,bnsr->bngs", qmg, mbuf) ** 2
-    valid = (jnp.arange(blk) <= fill)[None, None, None, :]
+    valid = (jnp.arange(blk)[None, :] <= fill[:, None])[:, None, None, :]
     w = jnp.where(valid, w, 0.0)
-
-    def values_and_ones():                                     # (B,Hkv,blk,h+1)
-        ones = jnp.ones((*vbuf.shape[:-1], 1), f32)
-        return jnp.concatenate([vbuf.astype(f32), ones], axis=-1)
-
-    local = jnp.einsum("bngs,bnsd->bngd", w, values_and_ones())
+    local = jnp.einsum("bngs,bnsd->bngd", w, vbuf.astype(f32))
 
     # --- sketched prefix (folded blocks) ---
     qf = self_kron(qmg)                                        # (B,Hkv,g,r^2)
-    cross = jnp.einsum("bngf,bnfd->bngd", qf, cache.z)
+    cross = jnp.einsum("bngf,bnfd->bngd", qf, layer_node(cache.z, layer))
 
     acc = local + cross
     out = (acc[..., :hd] / (1.0 + acc[..., hd:])).reshape(bsz, hq, hd)
+    return out.astype(v.dtype), _advance(cache, layer)
 
-    # --- fold the block into the prefix state when it completes ---
-    def fold(z):
-        # [v, 1] is built again inside the branch: taken from outside it
-        # would be an operand of the cond, written out on every step
+
+def polysketch_fold(cache: PolysketchCache, fold_due=None):
+    """Fold the blocks the last decode step completed into the prefix state.
+
+    A batch row whose buffer the step filled now sits on the block grid;
+    its block folds into z, and its buffer rows are overwritten by the
+    next block. `cache` may be layer-stacked (leaves (L, B, ...)): every
+    layer folds in turn inside one branch. `fold_due`: optional unbatched
+    scalar bool gating the fold, `polysketch_fold_due` of the whole state
+    before the step (some row of a batch the step is vmapped over
+    completes); None folds on this node's own positions alone.
+    """
+    blk = cache.kbuf.shape[-2]
+    stacked = cache.z.ndim == 5
+
+    def fold_layer(z, mbuf, vbuf, pos):
+        # the due rows fold; the others keep their z
+        done = jnp.broadcast_to(jnp.mod(pos, blk) == 0, z.shape[:1])
         kf = self_kron(mbuf)                                   # (B,Hkv,blk,r^2)
-        return z + jnp.einsum("bnsf,bnsd->bnfd", kf, values_and_ones())
+        new = z + jnp.einsum("bnsf,bnsd->bnfd", kf, vbuf.astype(jnp.float32))
+        return jnp.where(done[:, None, None, None], new, z)
 
-    # the scope holds the cond itself. Vmapped over serve slots, a cond on
-    # this cache's own `fill` is batched and lowers to a select that folds
-    # every slot on every step; an unbatched `fold_due` keeps it a real
-    # branch, and the due step selects per slot exactly as that select did
-    completes = fill == blk - 1
-    pred = completes if fold_due is None else fold_due
+    def fold(z):
+        if not stacked:
+            return fold_layer(z, cache.mbuf, cache.vbuf, cache.pos)
+        return jax.lax.fori_loop(0, z.shape[0], lambda i, z: put_layer_node(
+            z, i, fold_layer(*(layer_node(x, i) for x in
+                               (z, cache.mbuf, cache.vbuf, cache.pos)))), z)
+
+    # the scope holds the cond itself. An unbatched `fold_due` keeps it a
+    # real branch, taken on the few steps where some row completes a block
+    pred = (jnp.any(jnp.mod(cache.pos, blk) == 0) if fold_due is None
+            else fold_due)
     with jax.named_scope("fold"):
-        z = jax.lax.cond(pred, lambda z: jnp.where(completes, fold(z), z),
-                         lambda z: z, cache.z)
-    new_cache = PolysketchCache(z=z, kbuf=kbuf, vbuf=vbuf, mbuf=mbuf,
-                                pos=cache.pos + 1)
-    return out.astype(v.dtype), new_cache
+        return cache._replace(z=jax.lax.cond(pred, fold, lambda z: z,
+                                             cache.z))
 
 
 def polysketch_fold_due(cache: PolysketchCache):
@@ -136,7 +212,7 @@ class RecurrentCache(NamedTuple):
 class KVCache(NamedTuple):
     k: jax.Array    # (B, Hkv, S_max, h)
     v: jax.Array    # (B, Hkv, S_max, h)
-    pos: jax.Array  # ()
+    pos: jax.Array  # () or (B,)
 
 
 class RingKVCache(NamedTuple):
@@ -151,7 +227,7 @@ class RingKVCache(NamedTuple):
     """
     k: jax.Array    # (B, Hkv, W, h)
     v: jax.Array    # (B, Hkv, W, h)
-    pos: jax.Array  # ()
+    pos: jax.Array  # () or (B,)
 
 
 def init_kv_cache(batch, n_kv_heads, head_dim, max_len, dtype=jnp.float32) -> KVCache:
@@ -167,29 +243,30 @@ def init_ring_cache(batch, n_kv_heads, head_dim, window,
                        pos=jnp.zeros((), jnp.int32))
 
 
-def kv_ring_decode_step(cache: KVCache, q, k, v, *, scale: float | None = None):
+def kv_ring_decode_step(cache: RingKVCache, q, k, v, *,
+                        scale: float | None = None, layer=None):
     """Sliding-window softmax decode with a ring buffer of size W=max_len.
 
     The cache stores post-RoPE keys, so ring rotation does not disturb
-    relative positions. q: (B, Hq, h); k, v: (B, Hkv, h).
+    relative positions. q: (B, Hq, h); k, v: (B, Hkv, h); `layer` as in
+    polysketch_decode_step.
     """
     bsz, hq, hd = q.shape
     hkv = k.shape[1]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
-    w = cache.k.shape[2]
-    slot = jnp.mod(cache.pos, w)
-    kc = jax.lax.dynamic_update_index_in_dim(cache.k, k.astype(cache.k.dtype), slot, axis=2)
-    vc = jax.lax.dynamic_update_index_in_dim(cache.v, v.astype(cache.v.dtype), slot, axis=2)
+    w = cache.k.shape[-2]
+    pos = _row_pos(cache, layer, bsz)
+    cache = _write_rows(cache, layer, jnp.mod(pos, w), k=k, v=v)
+    kc, vc = layer_node(cache.k, layer), layer_node(cache.v, layer)
     qg = q.reshape(bsz, hkv, g, hd).astype(jnp.float32)
     logits = jnp.einsum("bngh,bnsh->bngs", qg, kc.astype(jnp.float32)) * scale
-    valid = jnp.arange(w) <= cache.pos  # until the ring is full
-    logits = jnp.where(valid[None, None, None, :], logits, jnp.finfo(jnp.float32).min)
+    valid = jnp.arange(w)[None, :] <= pos[:, None]  # until the ring is full
+    logits = jnp.where(valid[:, None, None, :], logits, jnp.finfo(jnp.float32).min)
     wts = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bngs,bnsh->bngh", wts, vc.astype(jnp.float32))
-    return (out.reshape(bsz, hq, hd).astype(v.dtype),
-            type(cache)(kc, vc, cache.pos + 1))
+    return out.reshape(bsz, hq, hd).astype(v.dtype), _advance(cache, layer)
 
 
 def ring_grid(block_size: int, window: int) -> int:
@@ -292,22 +369,24 @@ def kv_ring_prefill(cache: RingKVCache, q, k, v, *, grid: int,
             RingKVCache(rk, rv, cache.pos + jnp.asarray(s, jnp.int32)))
 
 
-def poly_kv_decode_step(cache: KVCache, q, k, v, *, degree: int, scale: float):
+def poly_kv_decode_step(cache: KVCache, q, k, v, *, degree: int, scale: float,
+                        layer=None):
     """Exact polynomial attention decode with a full KV cache (quadratic
     baseline; the paper's inference win is that polysketch does NOT need
-    this)."""
+    this). `layer` as in polysketch_decode_step."""
     bsz, hq, hd = q.shape
     hkv = k.shape[1]
     g = hq // hkv
-    kc = jax.lax.dynamic_update_index_in_dim(cache.k, k.astype(cache.k.dtype), cache.pos, axis=2)
-    vc = jax.lax.dynamic_update_index_in_dim(cache.v, v.astype(cache.v.dtype), cache.pos, axis=2)
+    pos = _row_pos(cache, layer, bsz)
+    cache = _write_rows(cache, layer, pos, k=k, v=v)
+    kc, vc = layer_node(cache.k, layer), layer_node(cache.v, layer)
     qg = q.reshape(bsz, hkv, g, hd).astype(jnp.float32)
     wts = (jnp.einsum("bngh,bnsh->bngs", qg, kc.astype(jnp.float32)) * scale) ** degree
-    mask = jnp.arange(kc.shape[2]) <= cache.pos
-    wts = jnp.where(mask[None, None, None, :], wts, 0.0)
+    mask = jnp.arange(kc.shape[2])[None, :] <= pos[:, None]
+    wts = jnp.where(mask[:, None, None, :], wts, 0.0)
     den = 1.0 + jnp.sum(wts, axis=-1, keepdims=True)
     out = jnp.einsum("bngs,bnsh->bngh", wts / den, vc.astype(jnp.float32))
-    return out.reshape(bsz, hq, hd).astype(v.dtype), KVCache(kc, vc, cache.pos + 1)
+    return out.reshape(bsz, hq, hd).astype(v.dtype), _advance(cache, layer)
 
 
 def polysketch_prefill(cache: PolysketchCache, qm, km, q, k, v, *,
@@ -364,58 +443,80 @@ def polysketch_prefill(cache: PolysketchCache, qm, km, q, k, v, *,
                                 pos=cache.pos + s)
 
 
-def broadcast_slot_caches(cache, slots: int):
-    """Replicate a batch-1 decode cache into a slot-stacked pytree.
+def slot_axis(path) -> int:
+    """The slot axis of a leaf of the engine's slot stack: where a batch-1
+    cache has its batch axis, right behind the layer axis in the model
+    cache's layer-stacked subtree ("groups", models/transformer.py
+    lm_init_cache) and leading elsewhere."""
+    return int(bool(path) and getattr(path[0], "key", None) == "groups")
 
-    Every leaf gains a leading slot axis: arrays (1, ...) -> (slots, 1, ...)
-    and the scalar `pos` becomes a (slots,) vector, so each serve slot
-    carries an independent position. Works for any of the cache pytrees in
-    this module (and the model-level dict-of-layers cache that stacks them).
+
+def broadcast_slot_caches(cache, slots: int):
+    """Replicate a batch-1 decode cache into the engine's slot stack.
+
+    The stack is layer-major: the slot axis takes the place of the batch
+    axis, behind the layer axis, so a layer-stacked leaf (L, 1, ...)
+    becomes (L, slots, ...) and an unstacked one (1, ...) becomes
+    (slots, ...). A per-layer position gains its slot axis at the same
+    place, (L,) -> (L, slots) and () -> (slots,), so each serve slot
+    carries an independent position. The stack is then a batch-`slots`
+    cache with one position per row, which the model's decode step runs
+    as it is. Works for any of the cache pytrees in this module and the
+    model-level dict-of-layers cache that stacks them.
     """
-    return jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (slots,) + x.shape).copy(), cache)
+    def one(path, x):
+        ax = slot_axis(path)
+        if x.ndim == ax:
+            x = jnp.expand_dims(x, ax)
+        return jnp.broadcast_to(
+            x, x.shape[:ax] + (slots,) + x.shape[ax + 1:]).copy()
+    return jax.tree_util.tree_map_with_path(one, cache)
 
 
 def slot_scatter(slot_caches, cache, slot):
-    """Write one slot's batch-1 cache into the slot-stacked pytree.
+    """Write one slot's batch-1 cache into the slot stack.
 
     `slot` may be a traced int32 scalar, so a single jitted scatter serves
-    every slot index without retracing. Leaves of `cache` must match the
-    slot-stacked leaves with the leading slot axis removed.
-    """
-    return jax.tree_util.tree_map(
-        lambda full, one: jax.lax.dynamic_update_index_in_dim(
-            full, one.astype(full.dtype), slot, axis=0),
-        slot_caches, cache)
+    every slot index without retracing."""
+    def one(path, full, x):
+        ax = slot_axis(path)
+        if x.ndim < full.ndim:
+            x = jnp.expand_dims(x, ax)
+        return jax.lax.dynamic_update_slice_in_dim(
+            full, x.astype(full.dtype), slot, axis=ax)
+    return jax.tree_util.tree_map_with_path(one, slot_caches, cache)
 
 
 def slot_gather(slot_caches, slot):
-    """Read one slot's batch-1 cache back out of the slot-stacked pytree."""
-    return jax.tree_util.tree_map(
-        lambda x: jax.lax.dynamic_index_in_dim(x, slot, axis=0,
-                                               keepdims=False), slot_caches)
+    """Read one slot's batch-1 cache back out of the slot stack, in the
+    form it had before stacking (a leaf with nothing behind its slot axis
+    is a per-slot position, and drops the axis)."""
+    def one(path, x):
+        ax = slot_axis(path)
+        y = jax.lax.dynamic_slice_in_dim(x, slot, 1, axis=ax)
+        return jnp.squeeze(y, ax) if x.ndim == ax + 1 else y
+    return jax.tree_util.tree_map_with_path(one, slot_caches)
 
 
 def kv_decode_step(cache: KVCache, q, k, v, *, scale: float | None = None,
-                   window: int | None = None):
-    """One softmax decode step with a (optionally sliding-window) KV cache.
+                   layer=None):
+    """One softmax decode step with a full KV cache.
 
-    q: (B, Hq, h); k, v: (B, Hkv, h). Returns (out (B, Hq, h), new_cache).
+    q: (B, Hq, h); k, v: (B, Hkv, h); `layer` as in polysketch_decode_step.
+    Returns (out (B, Hq, h), new_cache).
     """
     bsz, hq, hd = q.shape
     hkv = k.shape[1]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / float(hd) ** 0.5
-    kc = jax.lax.dynamic_update_index_in_dim(cache.k, k.astype(cache.k.dtype), cache.pos, axis=2)
-    vc = jax.lax.dynamic_update_index_in_dim(cache.v, v.astype(cache.v.dtype), cache.pos, axis=2)
+    pos = _row_pos(cache, layer, bsz)
+    cache = _write_rows(cache, layer, pos, k=k, v=v)
+    kc, vc = layer_node(cache.k, layer), layer_node(cache.v, layer)
     qg = q.reshape(bsz, hkv, g, hd).astype(jnp.float32)
     logits = jnp.einsum("bngh,bnsh->bngs", qg, kc.astype(jnp.float32)) * scale
-    idx = jnp.arange(kc.shape[2])
-    mask = idx <= cache.pos
-    if window is not None:
-        mask = mask & (idx > cache.pos - window)
-    logits = jnp.where(mask[None, None, None, :], logits, jnp.finfo(jnp.float32).min)
+    mask = jnp.arange(kc.shape[2])[None, :] <= pos[:, None]
+    logits = jnp.where(mask[:, None, None, :], logits, jnp.finfo(jnp.float32).min)
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bngs,bnsh->bngh", w, vc.astype(jnp.float32))
-    return out.reshape(bsz, hq, hd).astype(v.dtype), KVCache(kc, vc, cache.pos + 1)
+    return out.reshape(bsz, hq, hd).astype(v.dtype), _advance(cache, layer)

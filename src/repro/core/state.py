@@ -64,6 +64,8 @@ class NodeOps(NamedTuple):
     snapshot: Callable | None        # node -> constant-size snapshot pytree
     restore: Callable | None         # (fresh_node, snapshot, n_tokens) -> node
     fold_due: Callable | None = None  # node -> scalar bool; None: never folds
+    # (node, fold_due) -> node: after a decode step, fold what it completed
+    fold: Callable | None = None
 
 
 def _psk_snapshot(node: dec.PolysketchCache):
@@ -105,7 +107,8 @@ def _ring_restore(fresh: dec.RingKVCache, snap: dec.RingKVCache, n_tokens):
 
 NODE_OPS: dict[type, NodeOps] = {
     dec.PolysketchCache: NodeOps("block", _psk_snapshot, _psk_restore,
-                                 dec.polysketch_fold_due),
+                                 dec.polysketch_fold_due,
+                                 dec.polysketch_fold),
     dec.RecurrentCache: NodeOps("token", _rec_snapshot, _rec_restore),
     dec.RingKVCache: NodeOps("token", _ring_snapshot, _ring_restore),
     dec.KVCache: NodeOps(None, None, None),
@@ -116,6 +119,18 @@ _NODE_TYPES = tuple(NODE_OPS)
 
 def is_state_node(x) -> bool:
     return isinstance(x, _NODE_TYPES)
+
+
+def fold_blocks(state, fold_due=None):
+    """After a decode step, fold what it completed in every node of a
+    cache pytree (per-node dispatch; kinds with no fold pass through).
+    `fold_due`: one unbatched predicate gating every fold (`fold_due` of
+    the whole state before the step); None folds on each node's own
+    positions."""
+    def one(node):
+        fn = NODE_OPS[type(node)].fold
+        return node if fn is None else fn(node, fold_due)
+    return jax.tree_util.tree_map(one, state, is_leaf=is_state_node)
 
 
 def snapshot_state(state):
@@ -183,24 +198,27 @@ def batch_shard_axes(node):
 NODE_SHARD_AXES: dict[type, Callable] = {}
 
 
-def state_shard_axes(state, *, slot_stacked: bool = False):
+def state_shard_axes(state):
     """Logical-axes pytree mirroring a model cache pytree (per-node
     dispatch through the kind registry's declarations; leaves are tuples
     of logical names, consumable by distributed.sharding.shardings_for).
 
-    ``slot_stacked=True`` prepends a "batch" name per leaf for the
-    engine's slot-stacked form (leading slot axis over batch-1 caches) —
-    slots then spread over the "data" mesh axis while the inner batch-1
-    dim degrades to replicated via spec_for's used-set."""
-    def node_axes(node):
+    A batch-1 cache and the engine's slot stack share one naming: the
+    stack's slot axis sits where the batch axis does
+    (core.decode.broadcast_slot_caches), so it takes the node's "batch"
+    name (slots spread over the "data" mesh axis, while a batch-1 dim
+    degrades to replicated via spec_for's divisibility test), and the
+    stack's per-slot positions are named like a (batch,) leaf. The layer
+    axis of a layer-stacked node is "layers" (replicated)."""
+    def node_axes(path, node):
         fn = NODE_SHARD_AXES.get(type(node), batch_shard_axes)
-        if not slot_stacked:
+        lead = dec.slot_axis(path)
+        if not lead:
             return fn(node)
-        # the helpers key off leaf rank, so show them the UNSTACKED
-        # leaves (drop the leading slot axis), then prepend the slot
-        # dim's "batch" name
+        # the helpers key off leaf rank, so show them one layer's leaves,
+        # then name the layer axis
         inner = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), node)
+            lambda x: jax.ShapeDtypeStruct(x.shape[lead:], x.dtype), node)
 
         def is_names(t):
             # NB: cache nodes are NamedTuples (tuples themselves), so the
@@ -209,9 +227,10 @@ def state_shard_axes(state, *, slot_stacked: bool = False):
                 and all(isinstance(e, (str, type(None))) for e in t)
 
         return jax.tree_util.tree_map(
-            lambda names: ("batch",) + tuple(names),
+            lambda names: ("layers",) * lead + tuple(names),
             fn(inner), is_leaf=is_names)
-    return jax.tree_util.tree_map(node_axes, state, is_leaf=is_state_node)
+    return jax.tree_util.tree_map_with_path(node_axes, state,
+                                            is_leaf=is_state_node)
 
 
 # ---------------------------------------------------------------------------
@@ -479,11 +498,15 @@ class DecodeState:
         return logits[:, -1], state
 
     def decode_step(self, params, tok, pos, state, fold_due=None):
-        """tok (B, 1) at position `pos` (scalar; shared across the batch)
-        -> (logits (B, V), state). `fold_due`, one unbatched predicate
-        (`fold_due` of every state the step is vmapped over), gates the
-        block folds; None folds on each node's own position."""
-        positions = jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,))[:1]
+        """tok (B, 1) at position `pos` -> (logits (B, V), state). `pos` is
+        a scalar shared by the batch (a cache from `init`), or (B,), one
+        per row: the engine's slot stack, whose slot axis is the batch
+        axis (behind the layer axis of layer-stacked nodes) and whose
+        nodes keep a position per slot. `fold_due`, one unbatched
+        predicate (`fold_due` of the whole state), gates the block folds;
+        None folds on each node's own positions."""
+        pos = jnp.asarray(pos, jnp.int32)
+        positions = pos[:, None] if pos.ndim else pos[None]
         logits, state, _ = self._apply(params, {"tokens": tok},
                                        mode="decode", cache=state,
                                        positions=positions,
@@ -493,7 +516,7 @@ class DecodeState:
     @staticmethod
     def fold_due(state):
         """Scalar bool: the next decode step folds a block somewhere in
-        `state`, a cache pytree of any stacking (slots, layers); a
+        `state`, a cache pytree of any stacking (layers, slots); a
         constant False when no node kind folds."""
         due = jnp.asarray(False)
         for node in jax.tree_util.tree_leaves(state, is_leaf=is_state_node):

@@ -20,6 +20,8 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DEFAULT_RULES: dict[str | None, tuple[str, ...]] = {
@@ -85,19 +87,20 @@ def spec_for(names: tuple, shape: tuple, mesh: Mesh,
     return P(*out)
 
 
+def _is_names(x):
+    return isinstance(x, tuple) and all(
+        isinstance(e, (str, type(None))) for e in x)
+
+
 def shardings_for(axes_tree, shapes_tree, mesh: Mesh, rules=None):
     """Tree of NamedShardings for a params tree.
 
     axes_tree mirrors shapes_tree with tuples of logical names as leaves.
     """
-    def is_names(x):
-        return isinstance(x, tuple) and all(
-            isinstance(e, (str, type(None))) for e in x)
-
     def one(names, shaped):
         return NamedSharding(mesh, spec_for(names, shaped.shape, mesh, rules))
 
-    flat_axes = jax.tree_util.tree_flatten(axes_tree, is_leaf=is_names)[0]
+    flat_axes = jax.tree_util.tree_flatten(axes_tree, is_leaf=_is_names)[0]
     flat_shapes, treedef = jax.tree_util.tree_flatten(shapes_tree)
     assert len(flat_axes) == len(flat_shapes), \
         (len(flat_axes), len(flat_shapes))
@@ -157,3 +160,38 @@ def shard_act(x, *logical_names):
     names = tuple(logical_names) + (None,) * (x.ndim - len(logical_names))
     spec = spec_for(names, x.shape, mesh, rules)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def device_layouts(tree, axes_tree):
+    """The layout in which the active mesh's device holds each leaf's
+    shard by default, where that device tiles its arrays (TPU); None for
+    other leaves, and None outside the activation context. A loop that
+    carries such leaves keeps them in it (`keep_layouts`): left to choose,
+    the compiler may carry them in another layout, and relay the whole
+    tree at the loop's entry and exit."""
+    if not _ACT_CTX:
+        return None
+    mesh, rules = _ACT_CTX[-1]
+    dev = mesh.devices.flat[0]
+
+    def one(names, x):
+        shard = NamedSharding(mesh, spec_for(names, x.shape, mesh, rules)
+                              ).shard_shape(x.shape)
+        lay = Layout.from_pjrt_layout(
+            dev.client.get_default_layout(np.dtype(x.dtype), shard, dev))
+        return lay if lay.tiling else None
+
+    flat_axes = jax.tree_util.tree_flatten(axes_tree, is_leaf=_is_names)[0]
+    flat, treedef = jax.tree_util.tree_flatten(tree)
+    return jax.tree_util.tree_unflatten(
+        treedef, [one(a, x) for a, x in zip(flat_axes, flat)])
+
+
+def keep_layouts(tree, layouts):
+    """Constrain each leaf of `tree` to its layout in `layouts` (from
+    `device_layouts`; None leaves, or None, constrain nothing)."""
+    if layouts is None:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda x, lay: x if lay is None else with_layout_constraint(x, lay),
+        tree, layouts, is_leaf=lambda x: x is None)

@@ -95,17 +95,19 @@ def _out(params, y):
 def init_cache(params, cfg, kind: str, batch: int, max_len: int, dtype):
     # NB: every array leaf carries the batch on axis 0, but the scalar
     # `pos` has none — a batched cache shares one position. Serving slots
-    # at different depths therefore stack batch-1 caches on a fresh
-    # leading slot axis (core.decode.broadcast_slot_caches) instead of
-    # batching this one.
+    # at different depths therefore stack batch-1 caches with a slot axis
+    # in the batch axis's place and a `pos` per slot
+    # (core.decode.broadcast_slot_caches) instead of batching this one.
     spec = st.get_spec(st.mixer_state_kind(cfg, kind))
     return spec.init(cfg, batch, max_len, dtype)
 
 
 def attention_apply(params, cfg, x, *, kind: str, positions, mode: str,
                     cache=None, memory=None, impl: str | None = None,
-                    fold_due=None):
-    """Returns (y (B,S,D), new_cache_or_None)."""
+                    layer=None):
+    """Returns (y (B,S,D), new_cache_or_None). In decode mode `layer`
+    names the layer of a layer-stacked `cache` to step in place (None:
+    `cache` is this layer's own node)."""
     scale = cfg.attn_scale
     mech = cfg.attention if kind == "attn" else "softmax"
 
@@ -124,15 +126,16 @@ def attention_apply(params, cfg, x, *, kind: str, positions, mode: str,
             km = sketch_half(params["sketch"], k * rt, cfg.poly_degree, cfg.learned_sketch)
             y, cache = dec.polysketch_decode_step(
                 cache, qm, km, q, k, v, degree=cfg.poly_degree, scale=scale,
-                local_exact=cfg.local_exact, fold_due=fold_due)
+                local_exact=cfg.local_exact, layer=layer)
         elif skind == "poly_kv":
             q, k = _poly_ln(params, q, k)
             y, cache = dec.poly_kv_decode_step(cache, q, k, v,
-                                               degree=cfg.poly_degree, scale=scale)
+                                               degree=cfg.poly_degree,
+                                               scale=scale, layer=layer)
         elif skind == "kv_ring":
-            y, cache = dec.kv_ring_decode_step(cache, q, k, v)
+            y, cache = dec.kv_ring_decode_step(cache, q, k, v, layer=layer)
         else:
-            y, cache = dec.kv_decode_step(cache, q, k, v)
+            y, cache = dec.kv_decode_step(cache, q, k, v, layer=layer)
         return _out(params, y[:, :, None]), cache
 
     q, k, v = _project(params, cfg, x, positions, kind)

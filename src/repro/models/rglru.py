@@ -17,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.decode import RecurrentCache
+from repro.core.decode import RecurrentCache, layer_node, put_layer_node
 from repro.core.state import StateSpec, batch_shard_axes, register_state
 from repro.distributed.sharding import shard_act
 from repro.models.layers import dense_init
@@ -107,8 +107,9 @@ def _rglru_chunked(a, b, h0, chunk: int):
     return h, h_last
 
 
-def rglru_apply(params, cfg, x, *, mode="train", cache=None):
-    """x: (B,S,D) -> (y (B,S,D), new_cache).
+def rglru_apply(params, cfg, x, *, mode="train", cache=None, layer=None):
+    """x: (B,S,D) -> (y (B,S,D), new_cache). In decode mode `layer` names
+    the layer of a layer-stacked `cache` to step (core.decode.layer_node).
 
     Prefill resume: `cache` (zeros for a cold start) is the state the
     sequence continues from; the recurrence runs on a fixed
@@ -120,11 +121,14 @@ def rglru_apply(params, cfg, x, *, mode="train", cache=None):
     xin = shard_act(x @ params["w_in"].astype(dt), "batch", "seq", "rnn")
 
     if mode == "decode":
-        xc, conv_state = _conv4(params, xin, cache.conv)
+        # every leaf changes with each token: the whole node is written
+        node = layer_node(cache, layer)
+        xc, conv_state = _conv4(params, xin, node.conv)
         a, b = _rglru_coeffs(params, cfg, xc[:, 0])
-        h = a * cache.h + b
+        h = a * node.h + b
         y = h[:, None].astype(dt)
-        new_cache = RecurrentCache(h=h, conv=conv_state)
+        new_cache = put_layer_node(cache, layer,
+                                   RecurrentCache(h=h, conv=conv_state))
     elif mode == "prefill":
         resume = cache is not None
         xc, conv_state = _conv4(params, xin, cache.conv if resume else None)

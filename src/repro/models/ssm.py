@@ -20,7 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.decode import RecurrentCache
+from repro.core.decode import RecurrentCache, layer_node, put_layer_node
 from repro.core.state import StateSpec, batch_shard_axes, register_state
 from repro.distributed.sharding import shard_act
 from repro.models.layers import dense_init
@@ -199,8 +199,10 @@ def _ssd_batched(x, b, c, dt, neg_a, tri, s):
     return y.reshape(bs, nc * l, h, p)[:, :s]
 
 
-def ssm_apply(params, cfg, x, *, mode="train", cache=None):
-    """x: (B,S,D). Returns (y (B,S,D), new_cache).
+def ssm_apply(params, cfg, x, *, mode="train", cache=None, layer=None):
+    """x: (B,S,D). Returns (y (B,S,D), new_cache). In decode mode `layer`
+    names the layer of a layer-stacked `cache` to step
+    (core.decode.layer_node).
 
     Prefill resume: in prefill mode, `cache` (zeros for a cold start) is
     the state the sequence continues from — the conv window replays the
@@ -216,19 +218,22 @@ def ssm_apply(params, cfg, x, *, mode="train", cache=None):
     z, xbc, dt_raw = _split(params, cfg, x)
 
     if mode == "decode":
-        xbc_conv, conv_state = _conv(params, xbc, cache.conv)
+        # every leaf changes with each token: the whole node is written
+        node = layer_node(cache, layer)
+        xbc_conv, conv_state = _conv(params, xbc, node.conv)
         xin = xbc_conv[..., :d_inner]
         bmat = xbc_conv[..., d_inner:d_inner + n]
         cmat = xbc_conv[..., d_inner + n:]
         dt = jax.nn.softplus(dt_raw.astype(dt_f) + params["dt_bias"])
         a = -jnp.exp(params["A_log"].astype(dt_f)) * dt[:, 0]       # (B,H)
         xh = xin[:, 0].reshape(-1, heads, p).astype(dt_f)
-        hs = jnp.exp(a)[..., None, None] * cache.h + \
+        hs = jnp.exp(a)[..., None, None] * node.h + \
             dt[:, 0, :, None, None] * jnp.einsum("bn,bhp->bhnp", bmat[:, 0].astype(dt_f), xh)
         y = jnp.einsum("bn,bhnp->bhp", cmat[:, 0].astype(dt_f), hs)
         y = y + params["D"][None, :, None] * xh
         y = y.reshape(-1, 1, d_inner)
-        new_cache = RecurrentCache(h=hs, conv=conv_state)
+        new_cache = put_layer_node(cache, layer,
+                                   RecurrentCache(h=hs, conv=conv_state))
     else:
         resume = mode == "prefill" and cache is not None
         xbc_conv, conv_state = _conv(params, xbc,

@@ -23,7 +23,8 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import ssm as ssm_mod
-from repro.distributed.sharding import shard_act
+from repro.distributed.sharding import (device_layouts, keep_layouts,
+                                        shard_act)
 from repro.models.layers import (
     embedding_init, glu_ffn_apply, glu_ffn_init, norm_apply, norm_init,
 )
@@ -109,7 +110,7 @@ def lm_init(key, cfg):
 
 
 def _apply_block(bp, cfg, h, mixer_kind, ffn_kind, *, positions, mode, cache,
-                 impl, fold_due=None):
+                 impl, layer=None):
     h = shard_act(h, "batch", "seq", "embed")
     # the scope names ("mixer", "ffn", and "embed", "logits" in lm_apply)
     # label the compiled ops in a device trace; tests pin the vocabulary
@@ -118,13 +119,14 @@ def _apply_block(bp, cfg, h, mixer_kind, ffn_kind, *, positions, mode, cache,
         if mixer_kind in ("attn", "local_attn"):
             y, new_cache = attn.attention_apply(
                 bp["mixer"], cfg, hn, kind=mixer_kind, positions=positions,
-                mode=mode, cache=cache, impl=impl, fold_due=fold_due)
+                mode=mode, cache=cache, impl=impl, layer=layer)
         elif mixer_kind == "rglru":
             y, new_cache = rglru_mod.rglru_apply(bp["mixer"], cfg, hn,
-                                                 mode=mode, cache=cache)
+                                                 mode=mode, cache=cache,
+                                                 layer=layer)
         else:
             y, new_cache = ssm_mod.ssm_apply(bp["mixer"], cfg, hn, mode=mode,
-                                             cache=cache)
+                                             cache=cache, layer=layer)
         h = h + y
     if "ffn" not in bp:  # mixer-only block (mamba2)
         return h, new_cache, {}
@@ -154,8 +156,13 @@ def _add_aux(acc, aux):
 def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
              positions=None, image_embeds=None, impl: str | None = None,
              fold_due=None):
-    """tokens: (B, S) int32 (S==1 for decode). `fold_due` gates the
-    polysketch decode step's block fold (core.decode.polysketch_decode_step).
+    """tokens: (B, S) int32 (S==1 for decode).
+
+    In decode mode the layer loop carries the whole layer-stacked cache,
+    and each layer's decode step writes its token's rows into it in place
+    (core.decode); the blocks the step completed then fold, gated by
+    `fold_due` (core.state.fold_blocks). Prefill scans the layers' caches
+    as xs/ys, since a chunk rewrites whole buffers anyway.
 
     Returns (logits (B, S, V), new_cache, aux) — cache is None in train mode.
     """
@@ -174,20 +181,31 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
         positions = jnp.arange(tokens.shape[1])
 
     aux_total = _zero_aux(cfg)
+    gcaches = None if cache is None else cache["groups"]
+    carried = mode == "decode" and gcaches is not None
+    # the carried stack stays in the layout the device holds it in
+    pins = (device_layouts(cache, core_state.state_shard_axes(cache))
+            if carried else None)
 
     def group_body(carry, xs):
-        hh, auxc = carry
-        gp, gcache = xs
+        # decode: the carry holds the whole stack, stepped at layer `li`;
+        # otherwise xs hold this group's cache (None in training)
+        hh, auxc, stack = carry
+        gp, gcache, li = xs
+        caches = stack if carried else gcache
         new_caches = {}
         for bi, (mk, fk) in enumerate(pattern):
-            c_in = None if gcache is None else gcache[f"block{bi}"]
+            c_in = None if caches is None else caches[f"block{bi}"]
             hh, nc, aux = _apply_block(gp[f"block{bi}"], cfg, hh, mk, fk,
                                        positions=positions, mode=mode,
                                        cache=c_in, impl=impl,
-                                       fold_due=fold_due)
+                                       layer=li if carried else None)
             new_caches[f"block{bi}"] = nc
             auxc = _add_aux(auxc, aux)
-        return (hh, auxc), new_caches
+        if carried:
+            return (hh, auxc, keep_layouts(new_caches, pins and
+                                           pins["groups"])), None
+        return (hh, auxc, None), new_caches
 
     body = group_body
     if cfg.remat == "dots":
@@ -196,29 +214,31 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
     elif cfg.remat == "full":
         body = jax.checkpoint(group_body)
 
-    gcaches = None if cache is None else cache["groups"]
+    carry = (h, aux_total, gcaches if carried else None)
     if n_groups and cfg.unroll_layers:
         # Python loop (HLO contains every layer; used by dry-run cost probes)
         ncs = []
         for gi in range(n_groups):
             gp = jax.tree_util.tree_map(lambda x: x[gi], params["groups"])
-            gc = (None if gcaches is None else
+            gc = (None if gcaches is None or carried else
                   jax.tree_util.tree_map(lambda x: x[gi], gcaches))
-            (h, aux_total), nc = body((h, aux_total), (gp, gc))
+            carry, nc = body(carry, (gp, gc, gi))
             ncs.append(nc)
-        new_group_caches = (None if gcaches is None else
+        (h, aux_total, stack) = carry
+        new_group_caches = (stack if carried or gcaches is None else
                             jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ncs))
     elif n_groups:
-        xs = (params["groups"], gcaches)
         if gcaches is None:
             # scan needs matching pytree structures in xs; substitute params-only
-            (h, aux_total), _ = jax.lax.scan(
-                lambda c, p: (body(c, (p, None))[0], 0.0),
-                (h, aux_total), params["groups"])
+            (h, aux_total, _), _ = jax.lax.scan(
+                lambda c, p: (body(c, (p, None, None))[0], 0.0),
+                carry, params["groups"])
             new_group_caches = None
         else:
-            (h, aux_total), new_group_caches = jax.lax.scan(
-                body, (h, aux_total), xs)
+            xs = (params["groups"], None if carried else gcaches,
+                  jnp.arange(n_groups))
+            (h, aux_total, stack), ys = jax.lax.scan(body, carry, xs)
+            new_group_caches = stack if carried else ys
     else:
         new_group_caches = gcaches
 
@@ -229,8 +249,7 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
             c_in = None if cache is None else cache["tail"][f"block{bi}"]
             h, nc, aux = _apply_block(params["tail"][f"block{bi}"], cfg, h, mk,
                                       fk, positions=positions, mode=mode,
-                                      cache=c_in, impl=impl,
-                                      fold_due=fold_due)
+                                      cache=c_in, impl=impl)
             new_tail[f"block{bi}"] = nc
             aux_total = _add_aux(aux_total, aux)
 
@@ -247,6 +266,8 @@ def lm_apply(params, cfg, tokens, *, mode: str = "train", cache=None,
         new_cache = {"groups": new_group_caches}
         if rem:
             new_cache["tail"] = new_tail
+    if mode == "decode":
+        new_cache = core_state.fold_blocks(new_cache, fold_due)
     return logits, new_cache, aux_total
 
 
@@ -279,9 +300,11 @@ def lm_init_cache(params, cfg, batch: int, max_len: int):
 def lm_init_slot_cache(params, cfg, max_len: int):
     """Decode cache for one serve slot: batch 1, per-slot `pos` scalars.
 
-    The serve engine stacks these over a leading slot axis
-    (core.decode.broadcast_slot_caches) so every slot advances its own
-    position — the batched cache from lm_init_cache shares one `pos` and
-    cannot represent slots at different depths.
+    The serve engine stacks these layer-major
+    (core.decode.broadcast_slot_caches): the slot axis takes the batch
+    axis's place, behind the layer axis, and each `pos` gains a slot axis
+    there too, so every slot advances its own position — the batched cache
+    from lm_init_cache shares one `pos` and cannot represent slots at
+    different depths.
     """
     return lm_init_cache(params, cfg, 1, max_len)

@@ -14,6 +14,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.core.state import fold_blocks
 from repro.models import attention as attn
 from repro.distributed.sharding import shard_act
 from repro.models.layers import (
@@ -155,6 +156,8 @@ def whisper_decode(params, cfg, tokens, memory=None, *, mode="train",
     else:
         h, new_caches = jax.lax.scan(bodyw, h, (params["dec"], lcaches))
 
+    if mode == "decode":
+        new_caches = fold_blocks(new_caches)
     h = norm_apply(params["dec_norm"], h)
     logits = jnp.einsum("bsd,vd->bsv", h, params["embed"]["table"].astype(dt))
     return logits, new_caches

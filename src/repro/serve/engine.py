@@ -10,8 +10,9 @@ polysketch, softmax/poly KV, sliding-window ring, and SSM / RG-LRU
 recurrent-state models — any model whose `Model.state` is non-None.
 
 The engine keeps a fixed number of decode *slots*. Every slot owns an
-independent cache slice (the model's decode-state pytree at batch 1,
-stacked over a leading slot axis so each slot carries its own ``pos``).
+independent cache slice: the model's batch-1 decode-state pytrees are
+stacked layer-major, with the slot axis in the batch axis's place and a
+``pos`` per slot (core.decode.broadcast_slot_caches).
 Admission runs through a chunked prefill scheduler
 (serve/scheduler.py): each request's prompt is split into power-of-two
 block-bucketed chunks, and every tick dispatches at most a
@@ -19,9 +20,10 @@ block-bucketed chunks, and every tick dispatches at most a
 so a long prompt admits incrementally across ticks instead of stalling
 every live request for its whole prefill. The finished prefill (carried
 between chunks as a core.state.PartialPrefill) is scattered into the free
-slot with a jitted `dynamic_update_index_in_dim`; live slots are never
-touched. Decode runs all slots lockstep through one jitted, slot-vmapped
-tick; free slots decode along on stale state (their outputs are never
+slot with a jitted `dynamic_update_slice`; live slots are never
+touched. Decode runs all slots lockstep through one jitted tick, a batch
+of all slots whose layer loop writes each slot's token into the stack in
+place; free slots decode along on stale state (their outputs are never
 read, and admission rewrites the whole slot slice — cache, token, pos)
 until the queue refills them.
 
@@ -160,34 +162,32 @@ def make_decode_tick(state, plan: ServePlan, logprobs: bool):
     (tokens, logprobs, toks, pos, keys, caches, fold_due). Trace it inside
     `plan.activation_context()`.
 
+    `caches` is the layer-major slot stack
+    (core.decode.broadcast_slot_caches): its slot axis is the batch axis,
+    so one decode step runs every slot, and the layer loop writes each
+    slot's token into the stack in place.
+
     `fold_due` is one scalar for the whole tick: some slot's state
     completes a block, and folds it, on this tick (`state.fold_due`; False
-    for state kinds that never fold). It is read from the slot-stacked
-    caches' own positions, not from `pos`: a free slot's `pos` is frozen
+    for state kinds that never fold). It is read from the stack's own
+    per-slot positions, not from `pos`: a free slot's `pos` is frozen
     while its stale state decodes, and folds, along. It is computed
-    outside the slot vmap and passed in unbatched, so the fold stays a
-    real conditional that runs on the few ticks that need it; a predicate
-    read per slot inside the vmap is batched, and lowers to a select that
-    folds every slot on every tick."""
-
-    def decode_one(params, tok, pos, cache, fold_due):
-        logits, cache = state.decode_step(params, tok, pos, cache, fold_due)
-        return logits[0], cache
+    before the step and passed in unbatched, so the fold stays a real
+    conditional that runs on the few ticks that need it."""
 
     def decode_all(params, toks, pos, keys, samp, caches, active):
-        # model tick for all slots, then sampling OUTSIDE the vmap so
-        # a scalar lax.cond can skip the sampler ops entirely for
-        # all-greedy batches (a vmapped cond would lower to select and
-        # run both branches) — greedy-only serving keeps the pre-
-        # sampling argmax-tick cost. Free slots' stale params are
-        # ignored by the predicate (`| ~active`): a retired sampled
-        # request must not force the sampler on a greedy drain. Greedy
-        # slots never consume their PRNG stream, so the fast path
-        # leaving keys un-split is not observable in any request's
-        # tokens.
+        # model tick for all slots, then sampling in a scalar lax.cond
+        # that skips the sampler ops entirely for all-greedy batches (a
+        # batched cond would lower to select and run both branches) —
+        # greedy-only serving keeps the pre-sampling argmax-tick cost.
+        # Free slots' stale params are ignored by the predicate
+        # (`| ~active`): a retired sampled request must not force the
+        # sampler on a greedy drain. Greedy slots never consume their
+        # PRNG stream, so the fast path leaving keys un-split is not
+        # observable in any request's tokens.
         fold_due = state.fold_due(caches)
-        logits, caches = jax.vmap(decode_one, in_axes=(None, 0, 0, 0, None))(
-            params, toks, pos, caches, fold_due)
+        logits, caches = state.decode_step(params, toks[:, 0], pos, caches,
+                                           fold_due)
         # gather the vocab dim before softmax/argmax: the reductions
         # below must see identically-ordered operands on every mesh
         logits = plan.constrain_logits(logits)
@@ -311,7 +311,7 @@ class ServeEngine:
     Requests are admitted into free slots one at a time: each prefill runs
     at the request's own prompt length, and the resulting batch-1 state is
     scattered into the slot axis without disturbing live slots. All slots
-    then decode lockstep through one vmapped jitted step; each slot stops
+    then decode lockstep through one jitted step; each slot stops
     independently on EOS or its max-new-tokens budget.
 
     Decoding is per-request `SamplingParams` (greedy by default): the
@@ -391,10 +391,11 @@ class ServeEngine:
 
         state = self.state
 
-        # Device state: slot-stacked cache pytree (leading slot axis over
-        # batch-1 caches; per-slot `pos` scalars become a (slots,) vector),
-        # the next token to feed each slot, each slot's context depth, and
-        # the sampling state (per-slot PRNG key + stacked SamplingParams).
+        # Device state: the layer-major slot stack of the batch-1 caches
+        # (the slot axis in the batch axis's place, a `pos` per slot and
+        # layer), the next token to feed each slot, each slot's context
+        # depth, and the sampling state (per-slot PRNG key + stacked
+        # SamplingParams).
         slot_cache0 = state.init_slot(params, max_len)
         self._slot_caches = state.broadcast_slots(slot_cache0, slots)
         self._slot_tokens = jnp.zeros((slots, 1, 1), jnp.int32)
@@ -402,14 +403,13 @@ class ServeEngine:
         self._slot_keys = init_slot_keys(slots)
         self._slot_samp = init_slot_sampling(slots)
 
-        # placement: slot-stacked state spreads slots over "data", batch-1
-        # prefill caches shard kv-heads over "model", everything the host
-        # reads or writes per request stays replicated
+        # placement: the slot stack spreads slots over "data", both it and
+        # batch-1 prefill caches shard kv-heads over "model", everything
+        # the host reads or writes per request stays replicated
         plan_ = self.plan
         rep = plan_.replicated()
         cache1_sh = plan_.state_shardings(slot_cache0)
-        cacheS_sh = plan_.state_shardings(self._slot_caches,
-                                          slot_stacked=True)
+        cacheS_sh = plan_.state_shardings(self._slot_caches)
         tok_sh = plan_.slot_sharding(self._slot_tokens)
         pos_sh = plan_.slot_sharding(self._slot_pos)
         keys_sh = plan_.slot_sharding(self._slot_keys)
@@ -538,7 +538,7 @@ class ServeEngine:
                 samp, type(samp)(t, k, p, g))
             return caches, toks, pos, keys, samp
 
-        # The slot-stacked cache is donated on both hot paths (decode tick,
+        # The slot stack is donated on both hot paths (decode tick,
         # slot install) so XLA updates it in place instead of copying the
         # full cache pytree every generated token; callers must treat the
         # cache they pass in as consumed. Every entry point carries the

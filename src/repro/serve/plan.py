@@ -2,7 +2,7 @@
 
 One object answers every "where does this tensor live?" question the
 engine has: how params are partitioned (tensor-parallel on the "model"
-axis), how slot-stacked caches and per-slot sampling state spread over
+axis), how the layer-major slot stack and per-slot sampling state spread over
 the "data" axis, and what sharding each jitted entry point's inputs and
 outputs carry. Single-device serving is the trivial 1x1 plan — the same
 code path, with every spec degrading to replicated — so the engine has
@@ -141,10 +141,10 @@ class ServePlan:
         return jax.tree_util.tree_unflatten(
             treedef, [one(a, w) for a, w in zip(flat_axes, flat_w)])
 
-    def state_shardings(self, state, *, slot_stacked: bool = False):
-        """NamedSharding tree for a model cache pytree (or the engine's
-        slot-stacked form)."""
-        axes = state_shard_axes(state, slot_stacked=slot_stacked)
+    def state_shardings(self, state):
+        """NamedSharding tree for a model cache pytree (a batch-1 cache or
+        the engine's slot stack, whose slots spread over "data")."""
+        axes = state_shard_axes(state)
         return shardings_for(axes, state, self.mesh, SERVING_RULES)
 
     def slot_sharding(self, x) -> NamedSharding:

@@ -1,7 +1,7 @@
 """Per-request sampling for the serve stack (temperature / top-k / top-p).
 
 One fused sampler serves every consumer — `generate`, the engine's
-prefill first-token, and the slot-vmapped decode tick. Per-slot parameters
+prefill first-token, and the all-slot decode tick. Per-slot parameters
 live on device as stacked arrays (`SlotSampling`) next to the engine's
 `_slot_tokens`/`_slot_pos`, so a single jitted decode step samples all
 slots with *heterogeneous* params (a greedy request co-resident with a

@@ -8,7 +8,7 @@ import pytest
 from repro.core import (block_causal_linear_attention, init_polysketch_cache,
                         init_sketch, noncausal_linear_attention,
                         poly_attention_full, polysketch_decode_step,
-                        polysketch_prefill, qk_layernorm)
+                        polysketch_fold, polysketch_prefill, qk_layernorm)
 from repro.core.sketches import sketch_half
 
 
@@ -64,6 +64,7 @@ def test_decode_matches_train_exactly(local):
             cache, qm[None, t:t + 1], km[None, t:t + 1], q[None, t:t + 1],
             k[None, t:t + 1], v[None, t:t + 1], degree=4, scale=scale,
             local_exact=local)
+        cache = polysketch_fold(cache)
         outs.append(np.array(o[0, 0]))
     np.testing.assert_allclose(np.stack(outs), train_out, atol=1e-4)
 
@@ -86,6 +87,7 @@ def test_prefill_then_decode_matches_full(s0):
         o, cache = polysketch_decode_step(
             cache, qm[None, t:t + 1], km[None, t:t + 1], q[None, t:t + 1],
             k[None, t:t + 1], v[None, t:t + 1], degree=4, scale=scale)
+        cache = polysketch_fold(cache)
         outs.append(np.array(o[0, 0]))
     np.testing.assert_allclose(np.stack(outs), full[s0:], atol=1e-4)
 

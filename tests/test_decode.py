@@ -1,14 +1,14 @@
 """core/decode.py block-boundary edge cases: prefill at s % blk == 0,
 s < blk, and GQA all agree with the training block algorithm; a fold at
 exactly fill == blk - 1 matches the training path; sketch_param_count
-matches the real parameter tree; slot-stacked cache helpers round-trip."""
+matches the real parameter tree; slot-stack cache helpers round-trip."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (block_causal_linear_attention, init_polysketch_cache,
-                        init_sketch, polysketch_decode_step,
+                        init_sketch, polysketch_decode_step, polysketch_fold,
                         polysketch_prefill, qk_layernorm,
                         sketch_param_count)
 from repro.core.decode import (broadcast_slot_caches, init_kv_cache,
@@ -69,6 +69,7 @@ def test_prefill_boundary_then_decode_matches_train(s0, hq, hkv):
         o, cache = polysketch_decode_step(
             cache, qm[:, :, t], km[:, :, t], q[:, :, t], k[:, :, t],
             v[:, :, t], degree=4, scale=scale)
+        cache = polysketch_fold(cache)
         outs.append(np.asarray(o))
     np.testing.assert_allclose(np.stack(outs, axis=2),
                                full[:, :, s0:], atol=1e-4)
@@ -188,8 +189,9 @@ def test_ring_grid_divides_block_and_fits_window():
 
 
 def test_fold_at_block_edge_updates_prefix_state():
-    """The decode step at fill == blk - 1 must fold the completed block
-    into z; the next step then attends to it only through the sketch."""
+    """The decode step at fill == blk - 1 completes the block, and the
+    fold after it folds the block into z; the next step then attends to it
+    only through the sketch."""
     n = 2 * BLK
     q, k, v, qm, km, scale = _mh_setup(seed=9, n=n)
     cache = init_polysketch_cache(2, 2, 16, 8, BLK)
@@ -200,6 +202,8 @@ def test_fold_at_block_edge_updates_prefix_state():
     _, cache = polysketch_decode_step(
         cache, qm[:, :, BLK - 1], km[:, :, BLK - 1], q[:, :, BLK - 1],
         k[:, :, BLK - 1], v[:, :, BLK - 1], degree=4, scale=scale)
+    assert float(jnp.abs(cache.z).max()) == 0.0  # the step itself folds not
+    cache = polysketch_fold(cache)
     assert float(jnp.abs(cache.z).max()) > 0.0   # block folded exactly here
 
 
@@ -213,11 +217,12 @@ def test_sketch_param_count_matches_init(degree, learned):
 
 def test_slot_cache_helpers_roundtrip():
     """broadcast -> scatter -> gather preserves per-slot cache contents,
-    including the per-slot scalar pos."""
+    including the per-slot scalar pos; the slot axis takes the batch
+    axis's place."""
     cache = init_polysketch_cache(1, 2, 16, 8, BLK)
     slot_caches = broadcast_slot_caches(cache, 3)
     assert slot_caches.pos.shape == (3,)
-    assert slot_caches.kbuf.shape == (3, 1, 2, BLK, 16)
+    assert slot_caches.kbuf.shape == (3, 2, BLK, 16)
 
     filled = cache._replace(
         kbuf=jnp.ones_like(cache.kbuf), pos=jnp.asarray(5, jnp.int32))

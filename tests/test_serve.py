@@ -306,8 +306,8 @@ def test_engine_accounting():
 
 class _SelectFold:
     """The state protocol with every slot folding on its own position:
-    under the tick's slot vmap that predicate is batched, so the fold
-    lowers to a select computed for every slot on every tick."""
+    the fold's predicate is read from the caches inside the step instead
+    of being passed in."""
 
     def __init__(self, state):
         self.state = state
@@ -318,6 +318,38 @@ class _SelectFold:
 
     def fold_due(self, caches):
         return self.state.fold_due(caches)
+
+
+class _PerSlotStep:
+    """The state protocol with the tick's step taken slot by slot, as
+    before the slot stack went layer-major: each slot's batch-1 cache
+    steps alone under a vmap over slots (the fold gated by the same
+    unbatched predicate), and is written back whole."""
+
+    def __init__(self, state):
+        self._state = state
+
+    def __getattr__(self, name):
+        return getattr(self._state, name)
+
+    def decode_step(self, params, tok, pos, caches, fold_due=None):
+        st = self._state
+        if jnp.ndim(pos) == 0:         # one batch-1 cache (replay)
+            return st.decode_step(params, tok, pos, caches, fold_due)
+        slots = range(tok.shape[0])
+        ones = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[st.slot_gather(caches, si) for si in slots])
+
+        def one(t, p, c):
+            logits, c = st.decode_step(params, t[None], p, c, fold_due)
+            return logits[0], c
+
+        logits, ones = jax.vmap(one)(tok, pos, ones)
+        for si in slots:
+            caches = st.slot_scatter(
+                caches, jax.tree_util.tree_map(lambda x: x[si], ones), si)
+        return logits, caches
 
 
 def _psk_nodes(caches):
@@ -366,18 +398,64 @@ def _check_gated_fold(cache_pos, active, **overrides):
             jnp.asarray(active, bool))
     with plan.activation_context():
         got = jax.jit(make_decode_tick(st, plan, logprobs=True))(*args)
-        want = jax.jit(make_decode_tick(_SelectFold(st), plan,
-                                        logprobs=True))(*args)
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_array_equal(a, b)
+        for ref in (_SelectFold(st), _PerSlotStep(st)):
+            want = jax.jit(make_decode_tick(ref, plan, logprobs=True))(*args)
+            for a, b in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+                np.testing.assert_array_equal(a, b)
     completes = [n % blk == blk - 1 for n in cache_pos]
     assert bool(got[6]) == any(completes)
     # the tick folded exactly the slots whose block completed
-    for before, after in zip(_psk_nodes(caches), _psk_nodes(got[5])):
-        for si, done in enumerate(completes):
-            changed = not np.array_equal(before.z[si], after.z[si])
-            assert changed == done, (si, done)
+    for si, done in enumerate(completes):
+        before = _psk_nodes(st.slot_gather(caches, si))
+        after = _psk_nodes(st.slot_gather(got[5], si))
+        for b, a in zip(before, after):
+            assert (not np.array_equal(b.z, a.z)) == done, (si, done)
+
+
+# every state kind through the engine: the smoke config and overrides
+KINDS = {
+    "polysketch": ("gpt2s-polysketch", {}),
+    "kv_full": ("gpt2s-polysketch", dict(attention="softmax")),
+    "poly_kv": ("gpt2s-polysketch", dict(attention="polynomial")),
+    "kv_ring": ("gpt2s-polysketch",
+                dict(block_pattern=("local_attn",), sliding_window=8)),
+    "ssd": ("mamba2-780m", dict(lt_block_size=16)),
+    "rglru": ("recurrentgemma-9b",
+              dict(block_pattern=("rglru",), lt_block_size=16)),
+    "hybrid": ("gpt2s-polysketch", dict(block_pattern=("rglru", "attn"))),
+}
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_engine_bits_match_generate_and_per_slot_step(kind):
+    """Every state kind: slots at different depths decode across a block
+    fold, one retires early and its slot drains free, then takes a queued
+    request. Tokens equal solo generate() runs, and tokens and log-prob
+    bits equal an engine whose tick steps each slot's batch-1 cache
+    alone."""
+    arch, overrides = KINDS[kind]
+    cfg = get_config(arch, smoke=True).replace(**overrides)
+    model = build_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(7))
+    prompts = _prompts(cfg, [5, 14, 20, 9], seed=7)
+    gens = [22, 3, 12, 14]
+
+    def serve(m):
+        eng = ServeEngine(m, cfg, params, slots=3, max_len=64,
+                          logprobs=True)
+        for p, n in zip(prompts, gens):
+            eng.submit(p, n)
+        return {o.rid: o for o in eng.run()}
+
+    got = serve(model)
+    want = serve(model._replace(state=_PerSlotStep(model.state)))
+    for rid, (p, n) in enumerate(zip(prompts, gens)):
+        np.testing.assert_array_equal(
+            got[rid].tokens, _ref_tokens(model, cfg, params, p, n))
+        np.testing.assert_array_equal(got[rid].tokens, want[rid].tokens)
+        np.testing.assert_array_equal(got[rid].logprobs.view(np.uint32),
+                                      want[rid].logprobs.view(np.uint32))
 
 
 @pytest.mark.parametrize("overlap", [False, True])

@@ -217,6 +217,40 @@ def test_slot_helpers_roundtrip_recurrent_state():
     assert _leaves_equal(other, one)
 
 
+@pytest.mark.parametrize("kind", list(KIND_SETUPS))
+def test_slot_stack_roundtrip_and_snapshot_bytes(kind):
+    """A prefilled batch-1 cache scattered into the layer-major slot stack
+    gathers back bit for bit, its neighbours untouched, and a snapshot of
+    the gathered slot serializes to the same bytes as one taken before
+    stacking."""
+    model, cfg, params = _setup(kind)
+    st = model.state
+    one = st.init_slot(params, 48)
+    _, filled = st.prefill(params, _tokens(cfg, 2 * BLK, seed=3)[None],
+                           st.init_slot(params, 48))
+    stack = st.broadcast_slots(one, 3)
+    # layer-major: the slot axis sits behind the layer axis, in the batch
+    # axis's place, and every layer keeps a position per slot
+    for a, b in zip(jax.tree_util.tree_leaves(one["groups"]),
+                    jax.tree_util.tree_leaves(stack["groups"])):
+        want = ((a.shape[0], 3) + a.shape[2:] if a.ndim > 1
+                else a.shape + (3,))
+        assert b.shape == want, (a.shape, b.shape)
+    stack = st.slot_scatter(stack, filled, jnp.asarray(1, jnp.int32))
+    got = st.slot_gather(stack, jnp.asarray(1, jnp.int32))
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(filled)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(filled)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    assert _leaves_equal(st.slot_gather(stack, jnp.asarray(0, jnp.int32)),
+                         one)
+    if st.snapshot_granularity is not None:
+        assert (st.serialize(st.snapshot(got), 2 * BLK)
+                == st.serialize(st.snapshot(filled), 2 * BLK))
+
+
 def test_audio_model_has_no_decode_state():
     cfg = get_config("whisper-large-v3", smoke=True)
     assert build_model(cfg).state is None

@@ -26,7 +26,9 @@ from repro.serve.plan import ServePlan
 from repro.serve.sampling import init_slot_keys, init_slot_sampling
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
-SLOTS, MAX_LEN, CHUNK = 8, 3072 + 32, 1024
+MAX_LEN, CHUNK = 3072 + 32, 1024
+# the serving cells' ticks: slots and max_len of benchmarks/chip/traffic
+TICKS = {"gpt2s-polysketch": (16, 4608), "gpt2s-softmax": (8, 4608)}
 
 
 @pytest.fixture(scope="module")
@@ -123,20 +125,30 @@ def test_lt_mult_compiles(one_chip):
 
 
 def _compile_tick(one_chip, shapes):
-    """The engine's decode tick over SLOTS slots, compiled for the chip."""
+    """The engine's decode tick at its serving cell's slots and max_len,
+    compiled for the chip; returns (compiled, slot stack shapes)."""
     cfg, model, params, plan = shapes
+    slots, max_len = TICKS[cfg.name]
     st = model.state
     caches = _on(jax.eval_shape(
-        lambda p: st.broadcast_slots(st.init_slot(p, MAX_LEN), SLOTS),
+        lambda p: st.broadcast_slots(st.init_slot(p, max_len), slots),
         params), one_chip)
     s = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
-    keys = _on(jax.eval_shape(lambda: init_slot_keys(SLOTS)), one_chip)
-    samp = _on(jax.eval_shape(lambda: init_slot_sampling(SLOTS)), one_chip)
+    keys = _on(jax.eval_shape(lambda: init_slot_keys(slots)), one_chip)
+    samp = _on(jax.eval_shape(lambda: init_slot_sampling(slots)), one_chip)
     tick = make_decode_tick(st, plan, logprobs=True)
     with plan.activation_context():
         return jax.jit(tick, donate_argnums=(5,)).lower(
-            params, s((SLOTS, 1, 1), jnp.int32), s((SLOTS,), jnp.int32),
-            keys, samp, caches, s((SLOTS,), jnp.bool_)).compile()
+            params, s((slots, 1, 1), jnp.int32), s((slots,), jnp.int32),
+            keys, samp, caches, s((slots,), jnp.bool_)).compile(), caches
+
+
+@pytest.fixture(scope="module")
+def ticks(one_chip, model_shapes):
+    """Both serving configurations' compiled ticks, compiled once."""
+    return {"gpt2s-polysketch": _compile_tick(one_chip, model_shapes),
+            "gpt2s-softmax": _compile_tick(
+                one_chip, _published(GPT2_SMALL_SOFTMAX, one_chip))}
 
 
 def _conditional_paths(hlo_text):
@@ -146,8 +158,8 @@ def _conditional_paths(hlo_text):
             for m in [re.search(r'op_name="([^"]*)"', line)] if m]
 
 
-def test_engine_decode_tick_compiles(one_chip, model_shapes):
-    compiled = _compile_tick(one_chip, model_shapes)
+def test_engine_decode_tick_compiles(ticks):
+    compiled, _ = ticks["gpt2s-polysketch"]
     _fits_one_chip(compiled)
     text = compiled.as_text()
     # the layers' named scopes survive the chip's compiler in the ops'
@@ -156,18 +168,87 @@ def test_engine_decode_tick_compiles(one_chip, model_shapes):
     for scope in ("mixer", "sketch", "fold", "ffn", "logits", "sampler"):
         assert any(f"/{scope}/" in p or f"({scope})" in p
                    for p in paths), scope
-    # the block fold stays a branch under the slot vmap, taken on the
-    # ticks where some slot completes a block, and not a select that
-    # folds every slot on every tick
+    # the block fold stays a branch, taken on the ticks where some slot
+    # completes a block, and not a select that folds every slot on every
+    # tick
     assert any("/fold/" in p for p in _conditional_paths(text))
 
 
-def test_engine_softmax_decode_tick_compiles(one_chip):
-    compiled = _compile_tick(one_chip,
-                             _published(GPT2_SMALL_SOFTMAX, one_chip))
+def test_engine_softmax_decode_tick_compiles(ticks):
+    compiled, _ = ticks["gpt2s-softmax"]
     _fits_one_chip(compiled)
     paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
     assert not any("/fold/" in p for p in paths)
+
+
+_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _computations(hlo_text):
+    """{computation name: its instruction lines} of a compiled program."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line)
+    return comps
+
+
+def _fold_computations(comps):
+    """Every computation that runs inside the fold's conditional."""
+    todo = []
+    for lines in comps.values():
+        for line in lines:
+            if " conditional(" in line and "/fold/" in line:
+                todo += re.findall(
+                    r"%([\w.\-]+)",
+                    re.search(r"branch_computations=\{([^}]*)\}",
+                              line).group(1))
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)",
+                               line)
+            b = re.search(r"branch_computations=\{([^}]*)\}", line)
+            if b:
+                todo += re.findall(r"%([\w.\-]+)", b.group(1))
+    return seen
+
+
+def _copies(lines):
+    """(name, bytes) of every copy instruction among `lines`."""
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* copy\(",
+                     line)
+        if m and m.group(2) in _BYTES:
+            n = int(np.prod([int(d) for d in m.group(3).split(",") if d]))
+            yield m.group(1), n * _BYTES[m.group(2)]
+
+
+@pytest.mark.parametrize("name", list(TICKS))
+def test_decode_tick_moves_no_whole_slot_cache(ticks, name):
+    """The tick writes each slot's token into the layer-major slot stack
+    in place: its temporaries stay below the stack's own bytes, and no
+    copy outside the fold's branch is as large as one layer of any of the
+    stack's caches (the stack is not relaid, and no layer is written
+    back whole)."""
+    compiled, caches = ticks[name]
+    leaves = [x for x in jax.tree_util.tree_leaves(caches) if x.ndim > 2]
+    state = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves)
+    assert compiled.memory_analysis().temp_size_in_bytes < state
+    layer = min(int(np.prod(x.shape[1:])) * x.dtype.itemsize
+                for x in leaves)
+    comps = _computations(compiled.as_text())
+    fold = _fold_computations(comps)
+    big = [(c, n, b) for c, lines in comps.items() if c not in fold
+           for n, b in _copies(lines) if b >= layer]
+    assert not big, big
 
 
 def test_engine_prefill_chunk_compiles(one_chip, model_shapes):
